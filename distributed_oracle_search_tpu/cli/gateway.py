@@ -36,6 +36,7 @@ import threading
 
 from ..gateway import GatewayConfig, GatewayTier
 from ..obs import metrics as obs_metrics
+from ..utils.compile_cache import use_compile_cache
 from ..utils.log import get_logger, set_verbosity
 
 log = get_logger(__name__)
@@ -109,6 +110,7 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     set_verbosity(args.verbose)
+    use_compile_cache()
     if args.test:
         import os
 
@@ -182,6 +184,12 @@ def main(argv=None) -> int:
         for ep in tier.endpoints:
             log.info("gateway listening at %s", ep)
         status_providers = {"gateway": tier.statusz}
+        if args.backend == "inproc":
+            # this process holds the device; a host-backend tier must
+            # stay off it (its worker servers need the chip)
+            from ..obs.device import device_status
+
+            status_providers["device"] = device_status
         for fid, (fe, _fam) in enumerate(replicas):
             status_providers[f"serving_f{fid}"] = fe.statusz
         obs_srv = start_obs_server(

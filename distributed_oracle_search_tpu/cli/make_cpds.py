@@ -37,7 +37,8 @@ import sys
 from .args import parse_args
 from ..transport.launch import launch, session_name
 from ..utils.atomicio import sweep_stale_artifacts
-from ..utils.config import ClusterConfig, test_config
+from ..utils.compile_cache import use_compile_cache
+from ..utils.config import ClusterConfig, test_config, test_worker_count
 from ..utils.log import get_logger, set_verbosity
 
 log = get_logger(__name__)
@@ -238,8 +239,17 @@ def run_tpu(conf: ClusterConfig, args) -> None:
     oracle = CPDOracle(graph, dc, mesh=mesh)
     oracle.build(chunk=args.chunk)
     oracle.save(conf.outdir, codec=getattr(args, "codec", None))
-    print(f"built sharded CPD for {graph.n} nodes over "
-          f"{conf.maxworker} mesh shards -> {conf.outdir}")
+    # which device holds each worker's rows: a mesh that silently put
+    # every shard on one device would still answer correctly
+    shard_devices = {
+        int(s.index[0].start or 0): s.device.id
+        for s in oracle.fm.addressable_shards}
+    dev = jax.devices()[0]
+    print(json.dumps({
+        "built": conf.outdir, "nodes": int(graph.n),
+        "shards": conf.maxworker, "shard_devices": shard_devices,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())}}))
 
 
 def run_host(conf: ClusterConfig, args) -> None:
@@ -312,14 +322,13 @@ def run_host(conf: ClusterConfig, args) -> None:
 def main(argv=None) -> int:
     args = parse_args(argv, prog="make_cpds")
     set_verbosity(args.verbose)
+    use_compile_cache()
     if args.test:
-        import jax
-
         from ..data.synth import ensure_synth_dataset
 
-        # size the canned config to the local device count, like
-        # process_query's test mode — the two must build/read the same index
-        conf = test_config(n_workers=len(jax.devices()))
+        # sized like process_query's test mode — the two must build/read
+        # the same index
+        conf = test_config(n_workers=test_worker_count(args.backend))
         ensure_synth_dataset(os.path.dirname(conf.xy_file) or "./data")
     else:
         conf = ClusterConfig.load(args.c)

@@ -31,6 +31,7 @@ from .process_query import output, runtime_config
 from ..data.formats import read_diff, read_scen
 from ..transport.fifo import send_with_retry
 from ..transport.wire import Request, StatsRow, write_query_file
+from ..utils.compile_cache import use_compile_cache
 from ..utils.log import get_logger, set_verbosity
 from ..utils.timer import Timer
 
@@ -150,6 +151,7 @@ def send_fifo(part: np.ndarray, args, diff: str, nfs: str) -> list:
 def main(argv=None) -> int:
     args = parse_args(argv, prog="offline")
     set_verbosity(args.verbose)
+    use_compile_cache()
     if args.debug:
         args.omp, args.verbose = 1, max(args.verbose, 2)
         args.num_partitions = 1

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import csv
 import dataclasses
+import io
 import os
 import sys
 
@@ -48,8 +49,12 @@ from ..parallel.multihost import is_primary
 from ..transport import fifo as fifo_transport
 from ..transport import resilience
 from ..transport import rpc as rpc_transport
-from ..utils.atomicio import atomic_write_json, atomic_writer, sweep_stale_artifacts
-from ..utils.config import ClusterConfig, test_config
+from ..utils.atomicio import (
+    atomic_write_bytes, atomic_write_json, atomic_writer,
+    sweep_stale_artifacts,
+)
+from ..utils.compile_cache import use_compile_cache
+from ..utils.config import ClusterConfig, test_config, test_worker_count
 from ..utils.env import env_cast, env_flag
 from ..utils.log import get_logger, set_verbosity
 from ..utils.timer import Timer
@@ -339,6 +344,7 @@ def run_tpu(conf: ClusterConfig, args, queries, dc, diffs):
     owner = dc.worker_of(queries[:, 1])
     time_ns = get_time_ns(args)
     stats = []
+    answers = []
     paths = None
     # fused multi-diff: table-search trajectories are diff-independent
     # (moves follow the FREE-FLOW first-move table), so a multi-diff
@@ -408,6 +414,7 @@ def run_tpu(conf: ClusterConfig, args, queries, dc, diffs):
                         active_worker=args.worker)
             prep_iv, search_iv = prep.interval, search.interval
             H_SEARCH.observe(search_iv)
+        answers.append((cost, plen, fin))
         total_moves = int(plen[active].sum())
         total_size = int(active.sum())
         rows = []
@@ -455,6 +462,16 @@ def run_tpu(conf: ClusterConfig, args, queries, dc, diffs):
                                               active_worker=args.worker)
             paths = np.concatenate(
                 [queries, moves[:, None], nodes], axis=1)
+    if args.output and is_primary():
+        # the in-process mesh holds every answer, so the campaign can
+        # hand them over for checking: [rounds, Q] in scenario order
+        buf = io.BytesIO()
+        np.savez(buf, queries=queries,
+                 **{k: np.stack([np.asarray(a[i]) for a in answers])
+                    for i, k in enumerate(("cost", "plen", "finished"))})
+        os.makedirs(args.output, exist_ok=True)
+        atomic_write_bytes(os.path.join(args.output, "answers.npz"),
+                           buf.getvalue())
     return stats, paths
 
 
@@ -962,11 +979,9 @@ def test(args):
     """Canned smoke campaign on the synthetic dataset (parity: reference
     ``process_query.py:241-256``; TPU-mode by default, sized to the local
     device count)."""
-    import jax
-
     from ..data.synth import ensure_synth_dataset
 
-    conf = test_config(n_workers=len(jax.devices()))
+    conf = test_config(n_workers=test_worker_count(args.backend))
     ensure_synth_dataset(os.path.dirname(conf.xy_file) or "./data")
     data, stats, paths = run(conf, args)
     if is_primary():
@@ -993,6 +1008,7 @@ def _finish_obs(args) -> None:
 def main(argv=None) -> int:
     args = parse_args(argv, prog="process_query")
     set_verbosity(args.verbose)
+    use_compile_cache()
     if args.debug:
         # deterministic repro mode (parity: reference offline.py:143-147)
         args.omp, args.verbose = 1, max(args.verbose, 2)

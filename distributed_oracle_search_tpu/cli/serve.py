@@ -43,6 +43,7 @@ from ..transport import resilience
 from ..transport import rpc as rpc_transport
 from ..transport.fifo import command_fifo_path
 from ..transport.wire import RuntimeConfig
+from ..utils.compile_cache import use_compile_cache
 from ..utils.config import ClusterConfig, test_config
 from ..utils.log import get_logger, set_verbosity
 
@@ -219,6 +220,10 @@ def build_frontend(conf: ClusterConfig, args):
             graph_provider=lambda: Graph.from_xy(conf.xy_file),
             traffic=traffic)
     _build_integrity(frontend, dispatcher, icfg, args.backend)
+    if args.backend == "inproc":
+        # load and compile every shard on disk before the first client
+        # arrives (a shard without blocks still loads on first use)
+        frontend.warm(dispatcher.indexed_shards())
     return frontend, registry, families
 
 
@@ -323,6 +328,7 @@ def _dc_for(conf: ClusterConfig):
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     set_verbosity(args.verbose)
+    use_compile_cache()
     if args.test:
         from ..data.synth import ensure_synth_dataset
 

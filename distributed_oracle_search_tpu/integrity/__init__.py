@@ -24,8 +24,7 @@ depth:
     served batches re-execute on an independent lane — a replica
     engine, an uncached re-execution, or the CPU reference oracle for
     small batches, chosen by :func:`integrity.audit.choose_audit_lane`
-    (mirroring ``ops.pallas_walk.choose_walk_kernel``'s (choice, why)
-    contract) — and compare element-wise OFF the reply critical path.
+    as a ``(choice, why)`` pair — and compare element-wise OFF the reply critical path.
     A divergence books ``audit_divergence_total``, lands a structured
     ``audit_divergence`` flight-recorder event, and feeds the control
     loop's ``DivergenceWatch`` arm: breaker force-open, scrub-now,

@@ -9,8 +9,7 @@ batches on an **independent lane** and comparing element-wise, OFF the
 reply critical path — the client already has its answer; the audit
 decides whether to believe the engine going forward.
 
-Lane choice mirrors ``ops.pallas_walk.choose_walk_kernel``'s
-``(choice, why)`` contract (:func:`choose_audit_lane`):
+Lane choice is a ``(choice, why)`` pair (:func:`choose_audit_lane`):
 
 ``replica``
     another candidate worker for the same shard — an independent
@@ -80,8 +79,7 @@ def choose_audit_lane(candidates, via, nq: int, *,
                       max_reference: int) -> tuple[str, str]:
     """Pick the audit lane for one sampled batch → ``(lane, why)``.
 
-    Same shape as ``choose_walk_kernel``: the choice is a pure function
-    of what is available, and the ``why`` string is human-readable
+    The choice is a pure function of what is available, and the ``why`` string is human-readable
     policy provenance for the recorder event. Preference order is
     independence: ``replica`` (other resident copy) > ``reference``
     (other algorithm, small batches only) > ``recompute`` (same worker,

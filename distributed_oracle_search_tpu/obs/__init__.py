@@ -235,10 +235,8 @@ series every resident process exposes):
 * walk-kernel selection (``ops.pallas_walk`` via ``worker.engine``) —
   ``walk_{pallas,xla}_batches_total``: table-search batches by the
   kernel that answered them (``DOS_WALK_KERNEL`` resolution; a
-  pallas-requested batch that failed the VMEM-fit check books the
-  xla counter — the fleet-wide signal that ``auto`` actually engaged
-  the fused kernel, next to its ``table-search[pallas]/...`` program
-  cost capture).
+  pallas request that cannot run is refused and books neither), next
+  to the ``table-search[pallas]/...`` program cost capture.
 
 Worker mesh (multi-device sharded execution — one worker driving a
 lane mesh, ``DOS_MESH_DEVICES``; README "Worker mesh"):

@@ -60,8 +60,8 @@ def analyze(fn, *args, **kwargs) -> dict | None:
     """XLA cost + memory analysis of ``fn(*args, **kwargs)``.
 
     ``fn`` must be a ``jax.jit`` wrapper (it has ``.lower``); a bare
-    callable is jitted first. Any failure — old jaxlib without the AOT
-    API, a backend refusing analysis, a donation mismatch — returns
+    callable is jitted first. Any failure — a backend refusing
+    analysis, a donation mismatch — returns
     None with a debug log, never an exception into the serving path.
     """
     try:
@@ -72,8 +72,6 @@ def analyze(fn, *args, **kwargs) -> dict | None:
         out: dict = {}
         try:
             cost = lowered.cost_analysis()
-            if isinstance(cost, (list, tuple)):   # per-device lists on
-                cost = cost[0] if cost else {}    # some jax versions
             if cost:
                 out["flops"] = float(cost.get("flops", 0.0))
                 out["bytes_accessed"] = float(
@@ -170,6 +168,22 @@ def to_prometheus() -> str:
             lines.append(
                 f'device_program_{field}{{program="{esc}"}} {val:.10g}')
     return "\n".join(lines) + ("\n" if lines else "")
+
+
+def device_status() -> dict:
+    """The process's accelerator as JAX reports it, plus the memory
+    counters of its first device where the backend keeps them (the
+    ``device`` section of a server's ``/statusz``)."""
+    import jax
+
+    devices = jax.devices()
+    stats = devices[0].memory_stats() or {}
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+            **{k: int(stats[k]) for k in
+               ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+               if k in stats}}
 
 
 def reset() -> None:

@@ -40,17 +40,21 @@ kernel runs; the walk loop itself never changes.
 Kernel selection (``DOS_WALK_KERNEL``, via ``utils.env``):
 
 =========  ==========================================================
-``auto``   Pallas on real TPU backends, XLA everywhere else (default)
-``pallas`` force the fused kernel (interpret-mode on non-TPU hosts —
-           the parity/testing path, orders slower than XLA on CPU)
-``xla``    force the existing XLA walk (the reference implementation
-           and the CPU tier-1 path)
+``auto``   the XLA walk, on every backend (default)
+``pallas`` the fused kernel: interpret mode on non-TPU hosts (the
+           parity/testing path); refused with the reason on a TPU
+           (the compiler rejects it) and wherever the working set is
+           over the VMEM budget
+``xla``    the XLA walk (the reference implementation)
 =========  ==========================================================
 
-``auto``/``pallas`` additionally fall back to XLA when the bucket's
-row tile + graph tables exceed the VMEM budget
-(``DOS_WALK_VMEM_MB``) — an oversized shard degrades to the reference
-path, never faults on-chip.
+``auto`` does not pick this kernel on a TPU: the chip's compiler
+refuses it (Mosaic cannot lower its in-VMEM gathers, and its ``(1, qb)``
+bucket blocks break the (8, 128) tiling rule), and its working set
+is over the VMEM budget at any graph above a few thousand nodes
+(``tests/test_chip_compile.py``). An explicit ``pallas`` request that
+cannot run raises :class:`WalkKernelUnavailable`; it never serves the
+XLA walk under the Pallas name.
 
 Semantics are exactly :func:`.table_search.table_search_batch`'s
 (itself pinned to ``models.reference.table_search_walk``): free-flow
@@ -98,15 +102,16 @@ def walk_kernel_choice() -> str:
     return raw
 
 
-def resolve_walk_kernel(backend: str | None = None) -> str:
-    """Resolve the knob to a concrete kernel: ``auto`` picks Pallas on
-    real TPU backends and the XLA walk everywhere else (interpret-mode
-    Pallas is a correctness tool, not a serving path)."""
+def resolve_walk_kernel() -> str:
+    """Resolve the knob to a concrete kernel: ``auto`` is the XLA walk
+    on every backend (see the module docstring for why not Pallas on
+    a TPU)."""
     choice = walk_kernel_choice()
-    if choice != "auto":
-        return choice
-    platform = backend or jax.default_backend()
-    return "pallas" if platform == "tpu" else "xla"
+    return "xla" if choice == "auto" else choice
+
+
+class WalkKernelUnavailable(RuntimeError):
+    """An explicitly requested walk kernel cannot run at this shape."""
 
 
 def pallas_walk_fits(n: int, k: int, m: int, q: int,
@@ -123,8 +128,7 @@ def pallas_walk_fits(n: int, k: int, m: int, q: int,
     (``tl = ...astype(int32)`` — 4 bytes/lane, the dominant consumer;
     the pack4 unpack holds one extra int32 byte-gather temp of the same
     size while it widens), and the graph tables both as staged blocks
-    and as their flattened loop copies. Returns ``(ok, reason)`` so
-    callers can log the degrade once.
+    and as their flattened loop copies. Returns ``(ok, reason)``.
     """
     if q <= 0:
         return True, ""
@@ -147,27 +151,36 @@ def pallas_walk_fits(n: int, k: int, m: int, q: int,
         return False, (
             f"fused-walk working set {need / 2**20:.1f} MB "
             f"({codec} tile 2x{qb} rows + int32 widening + tables) over "
-            f"the {budget_mb:.0f} MB VMEM budget (DOS_WALK_VMEM_MB) — "
-            "falling back to the XLA walk")
+            f"the {budget_mb:.0f} MB VMEM budget (DOS_WALK_VMEM_MB)")
     return True, ""
 
 
+#: why an explicit ``pallas`` request is refused on a TPU: what the
+#: chip's compiler answered for a described v5e (the strict xfail in
+#: ``tests/test_chip_compile.py`` notices when it stops refusing)
+TPU_REFUSAL = ("the TPU compiler refuses the fused walk: Mosaic's "
+               "gather lowering rejects its in-VMEM gathers, and its "
+               "(1, qb) bucket blocks break the (8, 128) tiling rule")
+
+
 def choose_walk_kernel(n: int, k: int, m: int, q: int,
-                       codec: str = "raw") -> tuple[str, str]:
+                       codec: str = "raw") -> str:
     """The one selection site both serving paths call: resolve the
-    ``DOS_WALK_KERNEL`` knob, then degrade an over-budget pallas
-    request to the XLA walk. ``codec`` names the tile the kernel would
-    stage (``pack4`` = the compressed-resident nibble tile). Returns
-    ``(kernel, why)`` — ``why`` is non-empty exactly when a pallas
-    request fell back, so callers own only their log-once bookkeeping,
-    never the policy."""
+    ``DOS_WALK_KERNEL`` knob for a batch of ``q`` queries on a graph of
+    ``n`` nodes, max out-degree ``k`` and ``m`` edges. ``codec`` names
+    the tile the kernel would stage (``pack4`` = the compressed-
+    resident nibble tile). A ``pallas`` request that cannot run raises
+    :class:`WalkKernelUnavailable` with the reason."""
     kernel = resolve_walk_kernel()
     if kernel != "pallas":
-        return kernel, ""
+        return kernel
+    if jax.default_backend() == "tpu":
+        raise WalkKernelUnavailable(
+            f"DOS_WALK_KERNEL=pallas: {TPU_REFUSAL}")
     fits, why = pallas_walk_fits(n, k, m, q, codec=codec)
     if not fits:
-        return "xla", why
-    return "pallas", ""
+        raise WalkKernelUnavailable(f"DOS_WALK_KERNEL=pallas: {why}")
+    return "pallas"
 
 
 # ----------------------------------------------------- row-tile loaders
@@ -368,7 +381,7 @@ def _pallas_walk(dg: DeviceGraph, fm, t_rows, s, t, w_query_pad, valid,
             bucket_spec,                                   # s
             bucket_spec,                                   # t
             bucket_spec,                                   # valid
-            pl.BlockSpec(memory_space=pltpu.ANY),          # fm (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),             # fm (HBM)
             pl.BlockSpec((n, k), lambda i, sref: (0, 0)),  # out_nbr
             pl.BlockSpec((n, k), lambda i, sref: (0, 0)),  # out_eid
             pl.BlockSpec((1, w2.shape[1]),
@@ -377,13 +390,16 @@ def _pallas_walk(dg: DeviceGraph, fm, t_rows, s, t, w_query_pad, valid,
         out_specs=[bucket_spec, bucket_spec, bucket_spec],
         scratch_shapes=scratch,
     )
+    # under shard_map the outputs vary over the mesh axes the queries
+    # vary over (check_vma needs it said)
+    vma = jax.typeof(s2).vma
     cost, plen, fin = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((nb, qb), jnp.int32),
-            jax.ShapeDtypeStruct((nb, qb), jnp.int32),
-            jax.ShapeDtypeStruct((nb, qb), jnp.bool_),
+            jax.ShapeDtypeStruct((nb, qb), jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct((nb, qb), jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct((nb, qb), jnp.bool_, vma=vma),
         ],
         interpret=interpret,
     )(rows32, s2, t2, v2, fm, dg.out_nbr, dg.out_eid, w2)

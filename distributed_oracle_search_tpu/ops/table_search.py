@@ -200,9 +200,11 @@ def table_search_batch(dg: DeviceGraph, fm: jnp.ndarray,
     # contiguous 8-byte gather replaces the separate weight and
     # next-node gathers — 3 gathers/step -> 2, measured 1.5x on the
     # bench walk. Built once per call (one [N, K] pass, trivial vs the
-    # walk).
+    # walk). The weight gather runs on the [K, N] transpose: the TPU
+    # compiler takes ~30 s over an [N, K] index array at 102,400 nodes
+    # and well under a second over its transpose (compile-only, PR 21).
     pair = jnp.stack([dg.out_nbr.astype(jnp.int32),
-                      w_query_pad[dg.out_eid]], axis=-1)
+                      w_query_pad[dg.out_eid.T].T], axis=-1)
 
     slot_at, base_of = _fm_access(fm, r, n)
 

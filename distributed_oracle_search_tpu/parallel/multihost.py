@@ -32,8 +32,6 @@ Caveats worth knowing (multi-controller JAX semantics):
 
 from __future__ import annotations
 
-import os
-
 from ..utils.env import env_str
 from ..utils.log import get_logger
 
@@ -58,32 +56,9 @@ def initialize(coordinator: str | None = None,
         return
     if cpu_devices_per_process is not None:
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices",
-                              int(cpu_devices_per_process))
-        except AttributeError:
-            # older jax: only the env-flag spelling exists; honored as
-            # long as no backend has initialized yet
-            import re
-
-            flags = os.environ.get("XLA_FLAGS", "")
-            have = re.search(
-                r"--xla_force_host_platform_device_count=(\d+)", flags)
-            if have is None:
-                os.environ["XLA_FLAGS"] = (
-                    flags + " --xla_force_host_platform_device_count="
-                    + str(int(cpu_devices_per_process))).strip()
-            elif int(have.group(1)) != int(cpu_devices_per_process):
-                log.warning(
-                    "XLA_FLAGS already pins %s device(s), differing "
-                    "from cpu_devices_per_process=%d; keeping the "
-                    "existing flag", have.group(1),
-                    int(cpu_devices_per_process))
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except AttributeError:
-            pass
+        jax.config.update("jax_num_cpu_devices",
+                          int(cpu_devices_per_process))
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     kwargs = {}
     if coordinator is not None:
         kwargs["coordinator_address"] = coordinator

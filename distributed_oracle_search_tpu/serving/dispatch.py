@@ -34,6 +34,7 @@ record.
 from __future__ import annotations
 
 import dataclasses
+import glob
 import itertools
 import os
 import time
@@ -156,6 +157,18 @@ class EngineDispatcher:
                                 self.conf.outdir)
         build_worker_shard(self.graph, self.dc, shard, self.conf.outdir,
                            chunk=self.build_chunk, replica=replica)
+
+    def indexed_shards(self) -> list[int]:
+        """The shards whose primary block files are in the conf's
+        index directory: what a server can load and warm at start
+        (a shard without blocks stays lazy)."""
+        from ..models.cpd import shard_block_name
+
+        return [wid for wid in range(self.dc.maxworker)
+                if glob.glob(os.path.join(
+                    self.conf.outdir,
+                    shard_block_name(wid, 0)[:-len("00000.npy")]
+                    + "*.npy"))]
 
     def _rank_for(self, wid: int, via: int) -> int:
         """Which block set lane ``(wid, via)`` serves from: the via

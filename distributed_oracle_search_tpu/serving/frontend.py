@@ -190,6 +190,33 @@ class ServingFrontend:
                      self.sconf.cache_bytes >> 20)
         return self
 
+    def warm(self, shards) -> int:
+        """Load each listed shard and compile its walk at every batch
+        size the micro-batcher can hand it: the powers of two up to
+        ``max_batch`` (the engine pads every batch to one). One batch
+        of distinct owned pairs per size goes through the normal
+        dispatch path, below the cache. Without this the first client
+        requests wait for the shard load and one compile per size, and
+        on a chip that outlasts their deadline. Returns the batches
+        answered."""
+        n = self.dc.nodenum
+        batches = 0
+        for wid in shards:
+            owned = self.dc.owned(wid)
+            via = self._candidates(wid)[0]
+            t0 = time.perf_counter()
+            size = 1
+            while size <= self.sconf.max_batch:
+                i = np.arange(size)
+                queries = np.stack(
+                    [i % n, owned[(i // n) % len(owned)]], axis=1)
+                self._answer_once(wid, via, queries, self.diff)
+                batches += 1
+                size *= 2
+            log.info("shard %d warm: batch sizes 1..%d in %.1fs", wid,
+                     self.sconf.max_batch, time.perf_counter() - t0)
+        return batches
+
     def stop(self, drain_s: float = 5.0) -> None:
         """Shed new requests, drain admitted ones (bounded), join the
         batcher threads. ``drain_s`` is ONE shared budget across all

@@ -154,3 +154,15 @@ def test_config(datadir: str = "./data", n_workers: int = 8,
         scenfile=os.path.join(datadir, "synth.scen"),
         diffs=[os.path.join(datadir, "synth-city.xy.diff")],
     ).validate()
+
+
+def test_worker_count(backend: str) -> int:
+    """Shards of the canned ``-t`` config: one per local device when the
+    campaign runs in-process on the mesh, else 8 host workers. A
+    host-backend head never touches JAX: it would hold the chip its
+    worker processes need."""
+    if backend == "host":
+        return 8
+    import jax
+
+    return len(jax.devices())

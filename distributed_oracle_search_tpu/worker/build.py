@@ -24,6 +24,7 @@ import sys
 from ..data.graph import Graph
 from ..models.cpd import build_worker_shard
 from ..parallel.partition import DistributionController
+from ..utils.compile_cache import use_compile_cache
 from ..utils.log import get_logger, set_verbosity
 
 log = get_logger(__name__)
@@ -93,6 +94,7 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     set_verbosity(args.verbose)
+    use_compile_cache()
     outdir = args.outdir or os.path.dirname(os.path.abspath(args.input))
     partkey = args.partkey if args.partmethod == "alloc" else args.partkey[0]
 

@@ -72,8 +72,7 @@ M_WALK_PALLAS = obs_metrics.counter(
     "(DOS_WALK_KERNEL selection, ops.pallas_walk)")
 M_WALK_XLA = obs_metrics.counter(
     "walk_xla_batches_total",
-    "table-search batches answered by the XLA reference walk "
-    "(includes pallas-requested batches that fell back on VMEM fit)")
+    "table-search batches answered by the XLA reference walk")
 M_MESH_DEVICES = obs_metrics.gauge(
     "mesh_devices",
     "devices in this worker's local lane mesh (DOS_MESH_DEVICES "
@@ -292,9 +291,6 @@ class ShardEngine:
         self._astar_ctx: dict = {}
         #: path prefixes of the most recent extract batch (see answer())
         self.last_paths: tuple[np.ndarray, np.ndarray] | None = None
-        #: one log line per engine when a pallas-requested batch falls
-        #: back to XLA on the VMEM-fit check (not one per batch)
-        self._walk_fallback_logged = False
 
     # ------------------------------------------------------------- mesh
     @property
@@ -589,10 +585,9 @@ class ShardEngine:
                                       and not extracting
                                       and config.sig_k <= 0)
                           else "raw")
-            # kernel selection (DOS_WALK_KERNEL): the Pallas-fused walk
-            # on real TPU backends under `auto`, the XLA walk otherwise
-            # — with a VMEM-fit degrade so an oversized shard falls
-            # back to the reference path instead of faulting on-chip.
+            # kernel selection (DOS_WALK_KERNEL): the XLA walk unless
+            # the fused Pallas kernel is asked for by name, which is
+            # refused with the reason when it cannot run at this shape.
             # The choice joins the jit key: each kernel compiles (and
             # books its first-call compile time) separately.
             call_q = (self.astar_chunk
@@ -601,13 +596,10 @@ class ShardEngine:
             # lane-split batches: each device walks call_q / L queries,
             # so the VMEM-fit check sees the PER-LANE working set (the
             # same division CPDOracle._walk_kernel applies per shard)
-            kernel, why = choose_walk_kernel(
+            kernel = choose_walk_kernel(
                 self.dg.n, self.dg.k, int(self.dg.w_pad.shape[0]) - 1,
                 max(call_q // self.n_lanes, 1) if self._lane_split
                 else call_q, codec=tile_codec)
-            if why and not self._walk_fallback_logged:
-                log.warning("%s", why)
-                self._walk_fallback_logged = True
             use_tile_pack4 = (tile_codec == "pack4"
                               and kernel == "pallas")
             if kernel == "pallas":

@@ -1353,6 +1353,9 @@ def main(argv=None) -> int:
                         "DOS_RPC_PORT+wid when the env base is set)")
     args = p.parse_args(argv)
     set_verbosity(args.verbose)
+    from ..utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     set_worker_id(args.workerid)
 
     conf = ClusterConfig.load(args.c)

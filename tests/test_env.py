@@ -81,3 +81,31 @@ def test_atomic_replace_is_rename_based(tmp_path):
     atomic_replace_bytes(str(p), b"new contents")
     assert p.read_bytes() == b"new contents"
     assert [f for f in os.listdir(tmp_path) if ".tmp." in f] == []
+
+
+@pytest.mark.parametrize("env_dir", (None, "/somewhere/jax-cache"))
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and no other directory is set in
+    code; without it the cache goes to the fixed in-checkout path."""
+    import jax
+
+    from distributed_oracle_search_tpu.utils import compile_cache
+
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.use_compile_cache()
+        assert got == compile_cache.cache_dir()
+        if env_dir is None:
+            root = os.path.dirname(os.path.dirname(os.path.abspath(
+                __file__)))
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -29,6 +29,10 @@ from distributed_oracle_search_tpu.ops import (
     DeviceGraph, build_fm_columns, pallas_walk_batch, pallas_walk_fits,
     resolve_walk_kernel, table_search_batch,
 )
+from distributed_oracle_search_tpu.ops import pallas_walk as pw
+from distributed_oracle_search_tpu.ops.pallas_walk import (
+    WalkKernelUnavailable, choose_walk_kernel,
+)
 from distributed_oracle_search_tpu.ops.table_search import (
     BUCKET_MAX, pick_buckets,
 )
@@ -217,17 +221,30 @@ def test_conftest_pins_xla_for_tier1():
     assert resolve_walk_kernel() == "xla"
 
 
-def test_knob_resolution(monkeypatch):
+@pytest.mark.parametrize("backend", ("cpu", "tpu"))
+def test_knob_resolution(monkeypatch, backend):
+    """``auto`` is the XLA walk on every backend (the TPU compiler
+    refuses the fused kernel); only an explicit request picks Pallas."""
+    monkeypatch.setattr(pw.jax, "default_backend", lambda: backend)
     monkeypatch.setenv("DOS_WALK_KERNEL", "auto")
-    assert resolve_walk_kernel("cpu") == "xla"
-    assert resolve_walk_kernel("tpu") == "pallas"
+    assert resolve_walk_kernel() == "xla"
     monkeypatch.setenv("DOS_WALK_KERNEL", "pallas")
-    assert resolve_walk_kernel("cpu") == "pallas"
+    assert resolve_walk_kernel() == "pallas"
     monkeypatch.setenv("DOS_WALK_KERNEL", "XLA")       # case-tolerant
-    assert resolve_walk_kernel("tpu") == "xla"
+    assert resolve_walk_kernel() == "xla"
     monkeypatch.setenv("DOS_WALK_KERNEL", "bogus")     # degrade, not crash
-    assert resolve_walk_kernel("cpu") == "xla"
-    assert resolve_walk_kernel("tpu") == "pallas"
+    assert resolve_walk_kernel() == "xla"
+
+
+def test_explicit_pallas_refused_on_tpu_with_compiler_reason(monkeypatch):
+    """On a TPU an explicit pallas request raises with the compiler's
+    reason instead of serving the XLA walk."""
+    monkeypatch.setattr(pw.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("DOS_WALK_KERNEL", "auto")
+    assert choose_walk_kernel(48, 4, 164, 64) == "xla"
+    monkeypatch.setenv("DOS_WALK_KERNEL", "pallas")
+    with pytest.raises(WalkKernelUnavailable, match="gather lowering"):
+        choose_walk_kernel(48, 4, 164, 64)
 
 
 def test_vmem_fit_check(monkeypatch):
@@ -337,10 +354,11 @@ def test_engine_diffed_weights_pallas(toy_graph, shard_setup, tmp_path,
     assert (diffed[1] == free[1]).all()          # trajectory unchanged
 
 
-def test_engine_vmem_fallback_books_xla(toy_graph, shard_setup,
-                                        monkeypatch):
-    """A pallas-requested batch over the VMEM budget degrades to the
-    XLA walk (correct answers, xla counter booked) instead of faulting."""
+def test_engine_vmem_overrun_refuses_pallas(toy_graph, shard_setup,
+                                           monkeypatch):
+    """A pallas-requested batch over the VMEM budget is refused with
+    the reason, books no batch on either kernel, and the same engine
+    still answers once the request is withdrawn."""
     g = toy_graph
     dc, outdir = shard_setup
     nodes = np.arange(g.n)
@@ -351,13 +369,13 @@ def test_engine_vmem_fallback_books_xla(toy_graph, shard_setup,
     monkeypatch.setenv("DOS_WALK_VMEM_MB", "0.0001")
     snap0 = obs_metrics.REGISTRY.snapshot()["counters"]
     eng = ShardEngine(g, dc, wid=0, outdir=outdir)
-    cost, plen, fin, _ = eng.answer(queries, _engine_config())
+    with pytest.raises(WalkKernelUnavailable, match="VMEM budget"):
+        eng.answer(queries, _engine_config())
     snap1 = obs_metrics.REGISTRY.snapshot()["counters"]
-    assert fin.all()
-    assert snap1.get("walk_xla_batches_total", 0) \
-        == snap0.get("walk_xla_batches_total", 0) + 1
-    assert snap1.get("walk_pallas_batches_total", 0) \
-        == snap0.get("walk_pallas_batches_total", 0)
+    for name in ("walk_xla_batches_total", "walk_pallas_batches_total"):
+        assert snap1.get(name, 0) == snap0.get(name, 0)
+    monkeypatch.setenv("DOS_WALK_KERNEL", "auto")
+    assert eng.answer(queries, _engine_config())[2].all()
 
 
 # ------------------------------------------------- bench-diff gate

@@ -367,6 +367,30 @@ def test_diff_change_invalidates_cache(serve_world):
         fe.stop()
 
 
+def test_warm_compiles_every_batch_size_before_traffic(serve_world):
+    """Warming loads the indexed shards and compiles their walk at
+    every power of two up to max_batch, so live batches of any size
+    compile nothing."""
+    conf, g, dc, queries = serve_world
+    dispatcher = EngineDispatcher(conf, graph=g, dc=dc)
+    assert dispatcher.indexed_shards() == [0, 1]
+    fe = ServingFrontend(dc, dispatcher,
+                         sconf=ServeConfig(max_batch=16, max_wait_ms=1.0),
+                         diff=conf.diffs[1])
+    assert fe.warm(dispatcher.indexed_shards()) == 2 * 5   # 1..16
+    for wid in (0, 1):
+        assert len(dispatcher._engine_for(wid)._jit_seen) == 5
+    compiles0 = _hist("worker_jit_compile_seconds")["count"]
+    fe.start()
+    try:
+        futs = [fe.submit(int(s), int(t)) for s, t in queries[:40]]
+        res = [f.result(30) for f in futs]
+    finally:
+        fe.stop()
+    assert all(r.ok for r in res)
+    assert _hist("worker_jit_compile_seconds")["count"] == compiles0
+
+
 # ------------------------------------------------------ wire: fifo path
 
 def test_fifo_dispatcher_roundtrips_results(serve_world, tmp_path):
