@@ -36,11 +36,21 @@ behind, which keeps memo occupancy proportional to failures instead of
 total traffic. The ``blackhole-conn`` fault point turns one connection
 half-open — accepted, read, never answered — the asymmetric-partition
 drill.
+
+The gateway's own time: ``gateway_frame_seconds`` times each admitted
+query frame from the moment it was read off the socket to the moment its
+reply was written, and ``gateway_reply_seconds`` the pair frames' tail
+of it, from the frame's last answer to the reply written (the writer's
+wake, the in-order wait behind earlier frames, encode, send). The
+reader's parse and submit run in a ``gateway.frame`` span, the writer's
+encode and send in ``gateway.reply``, which carries the numbers of the
+batches that answered the frame.
 """
 
 from __future__ import annotations
 
 import collections
+import functools
 import os
 import socket
 import threading
@@ -51,6 +61,7 @@ from . import protocol
 from .config import GatewayConfig
 from ..obs import metrics as obs_metrics
 from ..obs import recorder as obs_recorder
+from ..obs import trace as obs_trace
 from ..testing import faults
 from ..transport.frames import (FrameReader, FrameWriter, TornFrame,
                                 TransportError)
@@ -84,6 +95,14 @@ M_DEDUP = obs_metrics.counter(
     "resubmitted query frames answered from the (cid, id) reply memo — "
     "counters and cache inserts not double-booked (exactly-once "
     "accounting over at-least-once execution)")
+H_FRAME = obs_metrics.histogram(
+    "gateway_frame_seconds",
+    "each admitted query frame, read off the socket until its reply was "
+    "written")
+H_REPLY = obs_metrics.histogram(
+    "gateway_reply_seconds",
+    "each answered pair frame, its last answer until its reply was "
+    "written: writer wake, in-order wait, encode, send")
 M_FAILOVER_FRAMES = obs_metrics.counter(
     "gateway_failover_frames_total",
     "resubmitted query frames this frontend had NOT answered before — "
@@ -268,6 +287,7 @@ class GatewayServer:
             while not self._stop.is_set():
                 try:
                     fr = reader.read()
+                    t_read = time.monotonic()
                 except TornFrame:
                     break        # client died mid-frame; nothing to
                     # answer — the typed-err contract covers frames
@@ -280,7 +300,7 @@ class GatewayServer:
                     conn_state["clean_eof"] = True
                     break
                 if not self._serve_frame(fr, pending, inflight,
-                                         conn_state):
+                                         conn_state, t_read):
                     break
         except (TransportError, OSError) as e:
             log.debug("gateway f%d connection dropped: %s", self.fid, e)
@@ -301,32 +321,53 @@ class GatewayServer:
 
     def _writer_loop(self, writer: FrameWriter, pending: queue.Queue,
                      inflight: list) -> None:
+        """Replies in frame-arrival order. Each item is ``(wait, build,
+        dedup_key, t_read)``: ``wait()`` blocks until the frame's
+        answers are in and ``build(answers)`` encodes them (no ``wait``:
+        ``build()``); ``t_read`` marks an admitted query frame."""
         while True:
             item = pending.get()
             if item is None:
                 return
-            waiter, is_q, dedup_key = item
+            wait, build, dedup_key, t_read = item
+            t_done, batches = None, ""
             try:
-                header, arrays = waiter()
-            except Exception as e:  # noqa: BLE001 — one bad frame must
-                # not wedge the writer; answer it typed and move on
-                log.warning("gateway f%d reply build failed: %s",
-                            self.fid, e)
-                header, arrays = protocol.error_frame(
-                    -1, f"internal: {e}", **self._ident())
-            if dedup_key is not None and header.get("kind") == "r":
-                # memoize BEFORE the send: a client that dies mid-reply
-                # resubmits, and the replay must cover exactly the
-                # frames whose accounting already booked
-                self._dedup_put(dedup_key, (header, arrays))
-            try:
-                writer.send(header, arrays)
-            except (TransportError, OSError):
-                return           # client is gone; reader will see EOF
-            finally:
-                if is_q:
-                    inflight[0] -= 1
-                    self.served += 1
+                if wait is not None:
+                    answers = wait()
+                    t_done, batches = _answered(answers)
+                    build = functools.partial(build, answers)
+            except Exception as e:  # noqa: BLE001 — answered typed below
+                build = functools.partial(self._internal_error, e)
+            with obs_trace.span("gateway.reply", frontend=self.fid,
+                                batches=batches):
+                try:
+                    header, arrays = build()
+                except Exception as e:  # noqa: BLE001 — one bad frame
+                    # must not wedge the writer; answer it typed and
+                    # move on
+                    header, arrays = self._internal_error(e)
+                if dedup_key is not None and header.get("kind") == "r":
+                    # memoize BEFORE the send: a client that dies
+                    # mid-reply resubmits, and the replay must cover
+                    # exactly the frames whose accounting already booked
+                    self._dedup_put(dedup_key, (header, arrays))
+                try:
+                    writer.send(header, arrays)
+                except (TransportError, OSError):
+                    return       # client is gone; reader will see EOF
+                finally:
+                    if t_read is not None:
+                        inflight[0] -= 1
+                        self.served += 1
+            if t_read is not None:
+                sent = time.monotonic()
+                H_FRAME.observe(sent - t_read)
+                if t_done is not None:
+                    H_REPLY.observe(sent - t_done)
+
+    def _internal_error(self, e: Exception):
+        log.warning("gateway f%d reply build failed: %s", self.fid, e)
+        return protocol.error_frame(-1, f"internal: {e}", **self._ident())
 
     def _dedup_put(self, key, reply) -> None:
         with self._dedup_lock:
@@ -351,9 +392,10 @@ class GatewayServer:
                 del self._dedup[k]
 
     def _serve_frame(self, fr, pending: queue.Queue, inflight: list,
-                     conn_state: dict) -> bool:
-        """Dispatch one client frame; False ends the connection (only
-        the schema gate does — malformed frames answer typed)."""
+                     conn_state: dict, t_read: float) -> bool:
+        """Dispatch one client frame (read off the socket at
+        ``t_read``); False ends the connection (only the schema gate
+        does — malformed frames answer typed)."""
         if conn_state["blackholed"] or faults.inject(
                 "blackhole-conn", wid=self.fid) is not None:
             # half-open partition: the socket stays accepted and
@@ -371,15 +413,15 @@ class GatewayServer:
                 self.malformed += 1
                 detail = str(e)
                 fid = protocol.frame_id(fr)
-                pending.put((lambda: protocol.error_frame(
-                    fid, detail, **ident), False, None))
+                pending.put((None, lambda: protocol.error_frame(
+                    fid, detail, **ident), None, None))
                 return False     # gate-newer: refuse service cleanly
             return True
         if fr.kind == "ping":
             h = dict(ident)
             h.update(kind="health", id=protocol.frame_id(fr),
                      ok=True, clients=self.clients, served=self.served)
-            pending.put((lambda: (h, []), False, None))
+            pending.put((None, lambda: (h, []), None, None))
             return True
         if fr.kind != "q":
             # unknown kinds are the receiver's to skip (the container
@@ -402,7 +444,7 @@ class GatewayServer:
                 # accounting; the client just never saw the answer)
                 M_DEDUP.inc()
                 self.deduped += 1
-                pending.put((lambda r=replay: r, False, None))
+                pending.put((None, lambda r=replay: r, None, None))
                 return True
             if fr.header.get("resubmit"):
                 # a failover arrival this frontend never answered:
@@ -413,23 +455,24 @@ class GatewayServer:
         if inflight[0] >= self.gconf.credit:
             M_BUSY.inc()
             self.busy += 1
-            pending.put((lambda: protocol.busy_frame(fid, **ident),
-                         False, None))
+            pending.put((None, lambda: protocol.busy_frame(fid, **ident),
+                         None, None))
             return True
-        try:
-            family, payload = protocol.parse_query_frame(fr)
-        except protocol.GatewayProtocolError as e:
-            M_MALFORMED.inc()
-            self.malformed += 1
-            detail = str(e)
-            pending.put((lambda: protocol.error_frame(
-                fid, detail, **ident), False, None))
-            return True
-        M_REQS.inc()
-        inflight[0] += 1
-        deadline_s = self._deadline_s(fr.header)
-        pending.put((self._submit(fid, family, payload, deadline_s),
-                     True, dedup_key))
+        with obs_trace.span("gateway.frame", frontend=self.fid, id=fid):
+            try:
+                family, payload = protocol.parse_query_frame(fr)
+            except protocol.GatewayProtocolError as e:
+                M_MALFORMED.inc()
+                self.malformed += 1
+                detail = str(e)
+                pending.put((None, lambda: protocol.error_frame(
+                    fid, detail, **ident), None, None))
+                return True
+            M_REQS.inc()
+            inflight[0] += 1
+            deadline_s = self._deadline_s(fr.header)
+            wait, build = self._submit(fid, family, payload, deadline_s)
+            pending.put((wait, build, dedup_key, t_read))
         return True
 
     def _deadline_s(self, header: dict) -> float:
@@ -441,61 +484,53 @@ class GatewayServer:
     # ------------------------------------------------------- family plumb
     def _submit(self, fid: int, family: str, payload, deadline_s: float):
         """Submit NOW (on the reader thread — admission and routing are
-        non-blocking), return the waiter the writer thread blocks on."""
+        non-blocking); return ``(wait, build)`` for the writer thread:
+        ``wait()`` blocks for the answers, ``build(answers)`` encodes
+        the reply (``wait`` None: ``build()``)."""
         ident = self._ident()
         if family == "pair":
             M_QUERIES.inc(len(payload))
             futs = [self.frontend.submit(int(s), int(t))
                     for s, t in payload]
             pairs = [(int(s), int(t)) for s, t in payload]
-
-            def wait_pairs():
-                rows = _drain(futs, pairs, deadline_s)
-                return protocol.reply_pairs(fid, "pair", rows, **ident)
-
-            return wait_pairs
+            return (lambda: _drain(futs, pairs, deadline_s),
+                    lambda rows: protocol.reply_pairs(fid, "pair", rows,
+                                                      **ident))
         # the typed families ride QueryFamilies.submit_line so they
         # inherit the brownout shed exactly like the line protocol
         fam = self.families
         if fam is None:
-            def no_families():
-                return protocol.reply_shed(
-                    fid, family, "ERROR", "family-not-served", **ident)
-            return no_families
+            return None, lambda: protocol.reply_shed(
+                fid, family, "ERROR", "family-not-served", **ident)
         if family == "rev":
             M_QUERIES.inc(len(payload))
             futs, pairs = [], []
             for s, t in payload:
                 futs.append(fam.submit_line("rev", (int(s), int(t))))
                 pairs.append((int(s), int(t)))
-
-            def wait_rev():
-                rows = _drain_rev(futs, pairs, deadline_s)
-                return protocol.reply_pairs(fid, "rev", rows, **ident)
-
-            return wait_rev
+            return (lambda: _drain_rev(futs, pairs, deadline_s),
+                    lambda rows: protocol.reply_pairs(fid, "rev", rows,
+                                                      **ident))
         if family == "mat":
             s, targets = payload
             M_QUERIES.inc(len(targets))
             fut = fam.submit_line("mat", (int(s), [int(t)
                                                    for t in targets]))
 
-            def wait_mat():
-                res = _family_result(fut, deadline_s)
+            def build_mat(res):
                 if not hasattr(res, "costs"):   # shed/errored
                     return protocol.reply_shed(
                         fid, "mat", getattr(res, "status", "ERROR"),
                         getattr(res, "detail", ""), **ident)
                 return protocol.reply_mat(fid, s, res.costs, **ident)
 
-            return wait_mat
+            return lambda: _family_result(fut, deadline_s), build_mat
         # alt
         s, t, k = payload
         M_QUERIES.inc()
         fut = fam.submit_line("alt", (int(s), int(t), int(k)))
 
-        def wait_alt():
-            res = _family_result(fut, deadline_s)
+        def build_alt(res):
             if not hasattr(res, "alternatives"):
                 return protocol.reply_shed(
                     fid, "alt", getattr(res, "status", "ERROR"),
@@ -503,7 +538,7 @@ class GatewayServer:
             return protocol.reply_alt(fid, s, t, res.alternatives,
                                       **ident)
 
-        return wait_alt
+        return lambda: _family_result(fut, deadline_s), build_alt
 
     # --------------------------------------------------------------- obs
     def statusz(self) -> dict:
@@ -533,6 +568,18 @@ class GatewayServer:
             out["l1_misses"] = int(fe_cache.misses)
             out["l1_hit_rate"] = round(fe_cache.hit_rate(), 4)
         return out
+
+
+def _answered(answers) -> tuple:
+    """``(t_done, batches)`` of a frame's result rows: when its last
+    answer was set, and the numbers of the batches that answered it
+    (``"3,4"``); ``(None, "")`` for a mat or alt result, or rows no
+    answer was set on."""
+    if not isinstance(answers, list):
+        return None, ""
+    t_done = max((r.t_done for r in answers), default=0.0)
+    batches = sorted({r.batch for r in answers if r.batch >= 0})
+    return (t_done or None), ",".join(map(str, batches))
 
 
 def _drain(futs, pairs, deadline_s: float):

@@ -10,16 +10,20 @@ every perf/robustness change reports through:
   ``bench.py`` embeds a snapshot in ``BENCH_DETAIL.json``); per-worker
   name suffixes (``serve_queue_depth_w3``) fold into
   ``{worker="3"}`` labels on the text exposition;
-* :mod:`.trace` — nested span tracing exporting Chrome trace-event JSON
-  (``--trace PATH``, open in Perfetto), with a per-batch ``trace_id``
-  propagated head→worker as a ``RuntimeConfig`` wire extension so both
-  sides of one batch join on a single timeline;
+* :mod:`.trace` — nested spans with two sinks: a
+  ``jax.profiler.TraceAnnotation`` on the profile's host plane whenever
+  JAX is imported and a profile records (same clock as the device's
+  programs), and Chrome trace-event JSON (``--trace PATH``, open in
+  Perfetto), with a per-batch ``trace_id`` propagated head→worker as a
+  ``RuntimeConfig`` wire extension so both sides of one batch join on a
+  single timeline;
 * :mod:`.quantiles` — live sliding-window p50/p95/p99 over the last N
   seconds (``DOS_OBS_WINDOW_S``) for the latency histograms that matter
   online (``serve_request_seconds``, ``serve_dispatch_seconds``,
   ``worker_search_seconds``), each window keeping a worst-case
-  **exemplar** ``trace_id`` that links a bad p99 to its Perfetto
-  timeline;
+  **exemplar** that links a bad p99 to its timeline: the batch
+  (``w<shard>.b<n>``) on the serving path, the wire ``trace_id`` on
+  the campaign path;
 * :mod:`.http` — the stdlib scrape server every resident process opts
   into with ``--obs-port`` / ``DOS_OBS_PORT``: ``/metrics`` (Prometheus
   text incl. live quantiles + per-program XLA costs), ``/healthz``
@@ -50,12 +54,16 @@ stats field    obs metrics covering the same interval
                additional interval. The query-file read happens in the
                server, outside the engine's timers, and appears as the
                ``worker.receive`` span only.
-``t_astar``    ``worker_search_seconds`` (the search call itself;
-               first-call XLA compile time is split out into
+``t_astar``    ``worker_search_seconds`` (the search call itself, walk
+               launch until the device finished; first-call XLA
+               compile time is split out into
                ``worker_jit_compile_seconds`` so steady-state latency
-               is not polluted by one-time compilation)
-``t_search``   receive + search — the worker's whole batch; the
-               head-side view of the same batch is
+               is not polluted by one-time compilation). The answers'
+               fetch to the host comes AFTER it, in
+               ``worker_fetch_seconds`` (table-search: device slices,
+               transfers, unsort, fan-out), outside every stats field
+``t_search``   receive + search — the worker's whole batch but the
+               fetch; the head-side view of the same batch is
                ``head_prepare_seconds`` + ``head_send_seconds``
                (FIFO round-trip, includes the worker's t_search)
 =============  =====================================================
@@ -112,7 +120,23 @@ admission decision, batch, and cache outcome is visible):
 * result cache — ``serve_cache_{hits,misses,evictions}_total``,
   ``serve_cache_{entries,bytes}`` gauges;
 * worker-side dedup (the batch-level twin of the cache) —
-  ``worker_duplicate_queries_total``.
+  ``worker_duplicate_queries_total``;
+* stage waits and spans (every batch carries a shard-local number,
+  ``batch=`` on each span below) — ``serve_queue_wait_seconds`` (each
+  request, enqueued until popped into a batch; span ``serve.collect``
+  on the collector), ``serve_handoff_wait_seconds`` (each batch,
+  flushed until the runner takes it from the depth-1 handoff; spans
+  ``serve.handoff``, the collector's blocked put, and ``serve.wait``,
+  the runner's empty get), then on the runner ``serve.dispatch``
+  around ``worker.prep`` (``worker_receive_seconds``, with
+  ``worker.weights`` nested), ``worker.walk``
+  (``worker_search_seconds``) and ``worker.fetch``
+  (``worker_fetch_seconds``), and ``serve.finish`` (cache puts and
+  futures set, inside ``serve_dispatch_seconds``);
+  ``worker_device_gap_seconds`` (table-search batches after an
+  engine's first: the previous batch's answers on the host until this
+  walk's launch — host time with nothing of the engine's queued on
+  its device).
 
 Artifact durability layer (the index data plane — atomic writes,
 checksummed manifests, crash-resume, self-healing loads; see the
@@ -294,6 +318,13 @@ client protocol, plus the shard-owner L2 result cache,
 * backpressure — ``gateway_busy_total`` (query frames refused with an
   explicit BUSY because the connection's credit window was full — the
   gateway twin of ``rpc_busy_frames_total``);
+* the gateway's own time — ``gateway_frame_seconds`` (each admitted
+  query frame, read off the socket until its reply was written; span
+  ``gateway.frame`` on the reader for parse and submit) and
+  ``gateway_reply_seconds`` (each answered pair frame, its last answer
+  until its reply was written: writer wake, in-order wait, encode,
+  send; span ``gateway.reply`` on the writer, carrying the frame's
+  ``batches``);
 * protocol hygiene — ``gateway_frames_malformed_total`` (client
   frames that failed to decode and were answered with a typed ERROR
   frame instead of a torn connection);

@@ -1,36 +1,55 @@
-"""Nested span tracing with Chrome trace-event export.
+"""Nested span tracing with two sinks: the JAX profiler and Chrome
+trace-event JSON.
 
 The timing half of the observability layer (``obs/``): phases of a query
-batch — head-side prepare/partition/send, worker-side
-receive/weights/search — run inside :func:`span` context managers, and
-the collected events serialize as Chrome trace-event JSON
-(``{"traceEvents": [...]}``, "X" complete events) loadable in Perfetto or
-``chrome://tracing``.
+batch — head-side prepare/partition/send, the serving path's collect,
+handoff, prep, walk, fetch and finish, the gateway's frame and reply —
+run inside :func:`span` context managers. One span feeds up to two
+sinks:
 
-Head and worker are separate processes in host mode, so spans join
-across the FIFO wire via a **trace id**: the head stamps each batch's
-``RuntimeConfig.trace_id`` (a backward-compatible wire extension — old
-servers filter the unknown key), the worker captures its spans for that
-batch under the same id and materializes them as a ``<queryfile>.trace``
-sidecar (the same shared-dir channel the ``.paths`` extension rides),
-and the head ingests the sidecars into one merged trace file.
+* **the profiler**: whenever JAX is already imported in the process and
+  a profile is recording, the span is a ``jax.profiler.TraceAnnotation``
+  — it lands on the host plane of the profile's ``.xplane.pb``
+  (``/host:CPU``, one line per thread) with its arguments, on the same
+  clock as the device's programs, so Perfetto or TensorBoard shows each
+  batch's host spans above the device work they drove. :func:`span`
+  never imports JAX itself: a JAX-free process (a benchmark client, a
+  ``--backend host`` gateway) pays one dictionary lookup.
+* **Chrome trace-event JSON** (``{"traceEvents": [...]}``, "X" complete
+  events, loadable in Perfetto or ``chrome://tracing``) when collection
+  is on: process-wide via :func:`enable` (``process_query --trace``) or
+  for one thread via :func:`capture`.
 
-Clock discipline: event **timestamps** are epoch microseconds
-(``time.time_ns``) so events from different processes land on one
-timeline without negotiation; **durations** come from the monotonic
-``perf_counter_ns`` so a span is immune to wall-clock steps.
+Head and worker are separate processes in host mode, so Chrome spans
+join across the FIFO wire via a **trace id**: the head stamps each
+batch's ``RuntimeConfig.trace_id`` (a backward-compatible wire extension
+— old servers filter the unknown key), the worker captures its spans for
+that batch under the same id and materializes them as a
+``<queryfile>.trace`` sidecar (the same shared-dir channel the
+``.paths`` extension rides), and the head ingests the sidecars into one
+merged trace file.
 
-Cost discipline: tracing is off by default, and a disabled :func:`span`
-returns one shared no-op context manager — no allocation, no clock
-read — so instrumented hot paths are no-op-cheap unless ``--trace``
-turns collection on process-wide or an incoming ``trace_id`` opens a
-per-thread :class:`capture` for one batch.
+**Tags**: :func:`tagged` sets arguments (the serving frontend's shard-
+local ``batch`` number and the batch ``size``) that every span the
+thread opens inside the block carries, in both sinks, so the engine's
+spans name the batch they served without knowing about batches.
+
+Clock discipline (Chrome sink): event **timestamps** are epoch
+microseconds (``time.time_ns``) so events from different processes land
+on one timeline without negotiation; **durations** come from the
+monotonic ``perf_counter_ns`` so a span is immune to wall-clock steps.
+
+Cost discipline: with no profile recording and Chrome collection off,
+:func:`span` returns one shared no-op context manager — no allocation,
+no clock read. An annotation while a profile records costs about a
+microsecond.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -38,7 +57,23 @@ import uuid
 _lock = threading.Lock()
 _events: list[dict] = []
 _enabled = False
-_tls = threading.local()
+
+
+class _ThreadState(threading.local):
+    """Per-thread state. The class attributes are each thread's defaults,
+    so a read never misses (a miss on a bare ``threading.local`` costs
+    about half a microsecond)."""
+
+    trace_id = None
+    capture = None
+    tags = None
+
+
+_tls = _ThreadState()
+#: ``jax.profiler.TraceAnnotation`` and its ``is_enabled`` (a profile is
+#: recording), once JAX is imported in the process
+_annotation_cls = None
+_recording = None
 
 
 def enable(on: bool = True) -> None:
@@ -65,6 +100,44 @@ def current_trace_id() -> str | None:
     return getattr(_tls, "trace_id", None)
 
 
+def current_tags() -> dict:
+    """The arguments :func:`tagged` set on this thread (empty outside
+    any block)."""
+    return getattr(_tls, "tags", None) or {}
+
+
+class tagged:
+    """Every span this thread opens inside the block carries ``tags``
+    as arguments (explicit span arguments win). Blocks nest; the outer
+    tags come back on exit."""
+
+    __slots__ = ("tags", "_prev")
+
+    def __init__(self, **tags):
+        self.tags = tags
+
+    def __enter__(self) -> "tagged":
+        self._prev = getattr(_tls, "tags", None)
+        _tls.tags = {**self._prev, **self.tags} if self._prev else self.tags
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        _tls.tags = self._prev
+        return False
+
+
+def _bind_profiler():
+    """``TraceAnnotation.is_enabled`` once JAX is imported here, else
+    None. Never imports JAX."""
+    global _annotation_cls, _recording
+    mod = sys.modules.get("jax._src.profiler")
+    if mod is None:
+        return None
+    _annotation_cls = mod.TraceAnnotation
+    _recording = _annotation_cls.is_enabled
+    return _recording
+
+
 class _NullSpan:
     """Shared do-nothing context manager: the disabled fast path."""
 
@@ -78,12 +151,6 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
-
-
-def _active() -> bool:
-    """Spans record when tracing is on process-wide OR this thread is
-    inside a :class:`capture` block."""
-    return _enabled or getattr(_tls, "capture", None) is not None
 
 
 def _emit(ev: dict) -> None:
@@ -114,13 +181,18 @@ def _make_event(name: str, ts_us: int, dur_us: int, args: dict) -> dict:
 
 
 class _Span:
-    __slots__ = ("name", "args", "_t0_wall_us", "_t0_perf")
+    """A Chrome event, and the profiler annotation when one records."""
 
-    def __init__(self, name: str, args: dict):
+    __slots__ = ("name", "args", "_ann", "_t0_wall_us", "_t0_perf")
+
+    def __init__(self, name: str, args: dict, ann):
         self.name = name
         self.args = args
+        self._ann = ann
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0_wall_us = time.time_ns() // 1000
         self._t0_perf = time.perf_counter_ns()
         return self
@@ -128,29 +200,29 @@ class _Span:
     def __exit__(self, *exc):
         dur_us = (time.perf_counter_ns() - self._t0_perf) // 1000
         _emit(_make_event(self.name, self._t0_wall_us, dur_us, self.args))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
 def span(name: str, **args):
-    """Context manager timing one phase. ``args`` land in the event's
-    ``args`` dict (``trace_id`` defaults to the thread's current id).
-    Returns a shared no-op when tracing is disabled."""
-    if not _active():
+    """Context manager timing one phase, in every sink that is on (see
+    the module docstring). ``args``, after the thread's :func:`tagged`
+    arguments, land in the event's arguments (``trace_id`` defaults to
+    the thread's current id in Chrome events). Returns a shared no-op
+    when no sink is on."""
+    recording = _recording or _bind_profiler()
+    profile = recording is not None and recording()
+    chrome = _enabled or getattr(_tls, "capture", None) is not None
+    if not (profile or chrome):
         return _NULL_SPAN
-    return _Span(name, args)
-
-
-def add_span(name: str, duration_s: float, **args) -> None:
-    """Record an already-measured phase as a complete event ending now.
-
-    For code that times itself with ``perf_counter`` deltas (the engine's
-    stats-field timers): the event's start is back-dated by the duration.
-    No-op when tracing is disabled."""
-    if not _active():
-        return
-    dur_us = int(duration_s * 1e6)
-    _emit(_make_event(name, time.time_ns() // 1000 - dur_us, dur_us,
-                      args))
+    tags = getattr(_tls, "tags", None)
+    if tags:
+        args = {**tags, **args}
+    if not chrome:
+        return _annotation_cls(name, **args)
+    return _Span(name, args,
+                 _annotation_cls(name, **args) if profile else None)
 
 
 def events() -> list[dict]:

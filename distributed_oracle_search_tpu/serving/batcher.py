@@ -11,6 +11,17 @@ behind, the handoff's backpressure makes waiting batches grow toward
 ``max_batch`` instead of racing out as singletons, which is what makes
 the batching *adaptive*: batch size tracks load.
 
+Each batch gets a shard-local sequence number when it forms, stamped on
+its requests (``ServeRequest.batch``). Every stage span carries it as
+``batch=`` — ``serve.collect`` (collector, in ``get_batch``),
+``serve.handoff`` (collector, blocked on the full handoff),
+``serve.wait`` (runner, waiting on an empty handoff) — and the runner
+dispatches inside :func:`obs.trace.tagged` ``(batch=, size=)``, so the
+frontend's and the engine's spans name the same batch. The histograms
+``serve_queue_wait_seconds`` (each request, enqueued until popped into
+a batch) and ``serve_handoff_wait_seconds`` (each batch, flushed until
+the runner takes it) time the two waits in front of a dispatch.
+
 Threads are named ``dos-serve-*`` — the test suite's leak check
 (tests/conftest.py) holds every ``dos-*`` thread to the
 joined-on-shutdown contract, and :meth:`MicroBatcher.stop` joins both.
@@ -23,6 +34,7 @@ import threading
 import time
 
 from ..obs import metrics as obs_metrics
+from ..obs import trace as obs_trace
 from ..utils.log import get_logger
 from .queue import ShardQueue
 from .request import ERROR, ServeRequest, ServeResult
@@ -44,6 +56,13 @@ H_FILL = obs_metrics.histogram(
 H_FLUSH = obs_metrics.histogram(
     "serve_time_to_flush_seconds",
     "first request enqueued until its batch flushed")
+H_QUEUE_WAIT = obs_metrics.histogram(
+    "serve_queue_wait_seconds",
+    "each request, enqueued until popped into a batch")
+H_HANDOFF_WAIT = obs_metrics.histogram(
+    "serve_handoff_wait_seconds",
+    "each batch, flushed until the runner takes it from the depth-1 "
+    "handoff")
 H_DISPATCH = obs_metrics.histogram(
     "serve_dispatch_seconds", "batch dispatch (engine call or wire "
     "round-trip) as seen by the runner thread")
@@ -83,9 +102,12 @@ class MicroBatcher:
 
     # ---------------------------------------------------------- threads
     def _collect_loop(self) -> None:
+        seq = 0
         while True:
-            batch = self.queue.get_batch(self.max_batch, self.max_wait_s,
-                                         self._stop)
+            with obs_trace.span("serve.collect", shard=self.wid,
+                                batch=seq):
+                batch = self.queue.get_batch(self.max_batch,
+                                             self.max_wait_s, self._stop)
             if not batch:
                 # a closed, drained queue is terminal (try_put refuses
                 # once closed): exit instead of spinning on instant
@@ -93,32 +115,46 @@ class MicroBatcher:
                 if self._stop.is_set() or self.queue.closed:
                     return
                 continue
+            flushed = time.monotonic()
+            for r in batch:
+                r.batch = seq
+                H_QUEUE_WAIT.observe(flushed - r.t_enqueue)
             H_FILL.observe(len(batch))
-            H_FLUSH.observe(time.monotonic() - batch[0].t_enqueue)
+            H_FLUSH.observe(flushed - batch[0].t_enqueue)
             (M_FLUSH_FULL if len(batch) >= self.max_batch
              else M_FLUSH_WAIT).inc()
-            while True:
-                try:
-                    self._handoff.put(batch, timeout=_HANDOFF_TICK_S)
-                    break
-                except _stdqueue.Full:
-                    if self._stop.is_set():
-                        _fail_batch(batch, "shutdown")
-                        return
+            with obs_trace.span("serve.handoff", shard=self.wid,
+                                batch=seq, size=len(batch)):
+                while True:
+                    try:
+                        self._handoff.put((seq, flushed, batch),
+                                          timeout=_HANDOFF_TICK_S)
+                        break
+                    except _stdqueue.Full:
+                        if self._stop.is_set():
+                            _fail_batch(batch, "shutdown")
+                            return
+            seq += 1
 
     def _run_loop(self) -> None:
+        seq = 0
         while True:
-            try:
-                batch = self._handoff.get(timeout=_HANDOFF_TICK_S)
-            except _stdqueue.Empty:
-                if self._stop.is_set():
-                    return
-                continue
+            with obs_trace.span("serve.wait", shard=self.wid, batch=seq):
+                while True:
+                    try:
+                        seq, flushed, batch = self._handoff.get(
+                            timeout=_HANDOFF_TICK_S)
+                        break
+                    except _stdqueue.Empty:
+                        if self._stop.is_set():
+                            return
+            H_HANDOFF_WAIT.observe(time.monotonic() - flushed)
             self._dispatching = True
             G_INFLIGHT.add(1)
             t0 = time.perf_counter()
             try:
-                self.dispatch(batch)
+                with obs_trace.tagged(batch=seq, size=len(batch)):
+                    self.dispatch(batch)
             except Exception as e:  # noqa: BLE001 — a dispatch bug must
                 # never strand waiters or kill the shard's runner
                 log.exception("shard w%d batch dispatch raised: %s",
@@ -129,6 +165,7 @@ class MicroBatcher:
                 H_DISPATCH.observe(time.perf_counter() - t0)
                 M_BATCHES.inc()
                 _fail_batch(batch, "dispatch-raised")  # only undone ones
+            seq += 1
 
     # --------------------------------------------------------- shutdown
     def stop(self, drain_s: float = 5.0) -> None:
@@ -149,7 +186,7 @@ class MicroBatcher:
         _fail_batch(self.queue.drain(), "shutdown")
         while True:
             try:
-                _fail_batch(self._handoff.get_nowait(), "shutdown")
+                _fail_batch(self._handoff.get_nowait()[2], "shutdown")
             except _stdqueue.Empty:
                 break
 

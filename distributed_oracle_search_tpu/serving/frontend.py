@@ -16,9 +16,11 @@
    through the configured dispatcher, records the breaker outcome,
    fills the cache, and completes every future.
 
-Every completion stamps the end-to-end latency histogram and (when
-tracing is enabled) a ``serve.request`` span, so the online path is
-observable from day one like the campaign path.
+Every completion stamps the end-to-end latency histogram, whose live
+window keeps the worst request's batch (``w<shard>.b<batch>``, the
+micro-batcher's shard-local number) as its exemplar; a batch's answers
+are cached and handed to their futures inside a ``serve.finish`` span,
+so a profile names the host work after every walk.
 """
 
 from __future__ import annotations
@@ -503,18 +505,14 @@ class ServingFrontend:
 
     def _finish(self, req: ServeRequest, res: ServeResult) -> None:
         res.t_done = time.monotonic()
+        res.batch = req.batch
         e2e = res.t_done - req.t_submit
         H_E2E.observe(e2e)
         # live sliding-window quantiles with an exemplar: the window's
-        # worst request keeps the trace id its batch dispatched under,
-        # so a bad p99 on the scrape links straight to its Perfetto
-        # timeline
+        # worst request names its batch, so a bad p99 on the scrape
+        # points at that batch's spans in a profile
         obs_quantiles.observe("serve_request_seconds", e2e,
-                              trace_id=req.trace_id)
-        obs_trace.add_span("serve.request", e2e, wid=req.wid,
-                           status=res.status,
-                           **({"trace_id": req.trace_id}
-                              if req.trace_id else {}))
+                              trace_id=batch_exemplar(req.wid, req.batch))
         req.future.set(res)
 
     def _dispatch_batch(self, wid: int, batch: list[ServeRequest]) -> None:
@@ -528,24 +526,7 @@ class ServingFrontend:
                                             detail="deadline"))
             else:
                 live.append(r)
-        if not live:
-            return
-        # with tracing on, every batch gets its own trace id: it rides
-        # the wire (RuntimeConfig extension) so the worker ships its
-        # spans back under it, it stamps each request (the quantile
-        # exemplar key), and it tags this thread's log records — scoped
-        # to this batch (the runner thread persists; a leaked id would
-        # mislabel between-batch log records with the PREVIOUS batch)
-        if obs_trace.enabled():
-            tid = obs_trace.new_trace_id()
-            obs_trace.set_trace_id(tid)
-            for r in live:
-                r.trace_id = tid
-            try:
-                self._dispatch_live(wid, live)
-            finally:
-                obs_trace.set_trace_id(None)
-        else:
+        if live:
             self._dispatch_live(wid, live)
 
     def _dispatch_live(self, wid: int, live: list[ServeRequest]) -> None:
@@ -580,7 +561,7 @@ class ServingFrontend:
             try:
                 cost, plen, fin, sigs = self._dispatch_hedged(
                     wid, via, candidates, queries, diff,
-                    depoch=depoch, tid=live[0].trace_id)
+                    depoch=depoch, batch=live[0].batch)
                 ok = True
             except Exception as e:  # noqa: BLE001 — any dispatch
                 # failure becomes a breaker failure record (booked by
@@ -606,32 +587,31 @@ class ServingFrontend:
                 M_ERRORS.inc()
                 self._finish(r, ServeResult(ERROR, r.s, r.t, detail=err))
             return
-        if self.auditor is not None:
-            # OFF the reply path: the clients' answers complete below
-            # regardless; the sampled dual execution decides whether to
-            # keep trusting this engine (integrity.audit)
-            self.auditor.maybe_submit(wid, via, candidates, queries,
-                                      self.rconf, diff, cost, plen, fin)
-        for i, r in enumerate(live):
-            val = (int(cost[i]), int(plen[i]), bool(fin[i]))
-            if (r.key[2] == diff
-                    and (len(r.key) <= 5 or r.key[5] == depoch)):
-                self.cache.put(r.key, val,
-                               sig=sigs[i] if sigs is not None
-                               else None)
-            M_OK.inc()
-            self._finish(r, ServeResult(OK, r.s, r.t, cost=val[0],
-                                        plen=val[1], finished=val[2]))
+        with obs_trace.span("serve.finish", shard=wid):
+            if self.auditor is not None:
+                # OFF the reply path: the clients' answers complete
+                # below regardless; the sampled dual execution decides
+                # whether to keep trusting this engine (integrity.audit)
+                self.auditor.maybe_submit(wid, via, candidates, queries,
+                                          self.rconf, diff, cost, plen,
+                                          fin)
+            for i, r in enumerate(live):
+                val = (int(cost[i]), int(plen[i]), bool(fin[i]))
+                if (r.key[2] == diff
+                        and (len(r.key) <= 5 or r.key[5] == depoch)):
+                    self.cache.put(r.key, val,
+                                   sig=sigs[i] if sigs is not None
+                                   else None)
+                M_OK.inc()
+                self._finish(r, ServeResult(OK, r.s, r.t, cost=val[0],
+                                            plen=val[1], finished=val[2]))
 
     # ------------------------------------------------- hedged dispatch
     def _answer_once(self, wid: int, via: int, queries, diff: str,
-                     depoch: int = 0, tid: str = ""):
+                     depoch: int = 0):
         """One dispatch lane; returns ``(cost, plen, fin, sigs)`` where
         ``sigs`` is a per-query path-signature list (or None when no
-        signatures were captured). ``tid`` is the batch's trace id: it
-        tags this thread (hedge lanes run on fresh threads that would
-        otherwise be untagged), rides the wire so a FIFO worker captures
-        its spans under it, and labels the dispatch span."""
+        signatures were captured)."""
         rconf = self.rconf
         epoch = (self.membership.epoch if self.membership is not None
                  else self.dc.epoch)
@@ -643,10 +623,6 @@ class ServingFrontend:
             # the traffic twin: the diff epoch this batch's fused file
             # was pinned at (tolerate-older / gate-newer on the worker)
             rconf = dataclasses.replace(rconf, diff_epoch=int(depoch))
-        if tid:
-            obs_trace.set_trace_id(tid)
-            if not rconf.trace_id:
-                rconf = dataclasses.replace(rconf, trace_id=tid)
         want_sigs = (self._sig_k > 0 and self.cache.enabled
                      and hasattr(self.dispatcher,
                                  "answer_batch_paths"))
@@ -700,7 +676,7 @@ class ServingFrontend:
 
     def _dispatch_hedged(self, wid: int, via: int, candidates,
                          queries, diff: str, depoch: int = 0,
-                         tid: str = ""):
+                         batch: int = -1):
         """One batch through ``via``, hedged: if no answer lands within
         the shard's adaptive delay (recent latency quantile, floor
         ``DOS_HEDGE_MIN_MS``) and the hedge budget grants, a duplicate
@@ -730,7 +706,7 @@ class ServingFrontend:
             t0 = time.monotonic()
             try:
                 out = self._answer_once(wid, via, queries, diff,
-                                        depoch=depoch, tid=tid)
+                                        depoch=depoch)
             except Exception:
                 self._record(via, False)
                 raise
@@ -738,15 +714,18 @@ class ServingFrontend:
             dt = time.monotonic() - t0
             self.hedge.observe(wid, dt)
             obs_quantiles.observe("serve_dispatch_seconds", dt,
-                                  trace_id=tid)
+                                  trace_id=batch_exemplar(wid, batch))
             return out
         results: _stdqueue.Queue = _stdqueue.Queue()
+        # the runner's batch tags follow the lanes onto their threads
+        tags = obs_trace.current_tags()
 
         def run(target: int, is_hedge: bool) -> None:
             t0 = time.monotonic()
             try:
-                r = self._answer_once(wid, target, queries, diff,
-                                      depoch=depoch, tid=tid)
+                with obs_trace.tagged(**tags):
+                    r = self._answer_once(wid, target, queries, diff,
+                                          depoch=depoch)
             except Exception as e:  # noqa: BLE001 — collected below
                 self._record(target, False)
                 results.put((is_hedge, None, e, time.monotonic() - t0))
@@ -789,5 +768,11 @@ class ServingFrontend:
             M_WON.inc()
         self.hedge.observe(wid, duration)
         obs_quantiles.observe("serve_dispatch_seconds", duration,
-                              trace_id=tid)
+                              trace_id=batch_exemplar(wid, batch))
         return out
+
+
+def batch_exemplar(wid: int, batch: int) -> str:
+    """The latency windows' exemplar for a batch: ``w<shard>.b<n>``
+    (empty for work no batch carried, such as warm-up)."""
+    return f"w{wid}.b{batch}" if batch >= 0 else ""

@@ -80,6 +80,9 @@ class ServeResult:
     cached: bool = False
     detail: str = ""
     t_done: float = 0.0
+    #: the shard-local number of the batch that answered it (-1: none,
+    #: e.g. a cache hit or a shed); names the batch's profiler spans
+    batch: int = dataclasses.field(default=-1, compare=False)
 
     @property
     def ok(self) -> bool:
@@ -100,7 +103,7 @@ class ServeResult:
 class ServeRequest:
     """One admitted in-flight request. ``t_submit`` anchors the
     end-to-end latency histogram; ``t_enqueue`` (stamped by the queue)
-    anchors the batcher's time-to-flush; ``deadline`` is absolute
+    anchors the batcher's time-to-flush and queue wait; ``deadline`` is absolute
     monotonic time after which dispatch completes the request
     ``TIMEOUT`` instead of running it."""
 
@@ -112,10 +115,11 @@ class ServeRequest:
     deadline: float | None = None
     future: Future = dataclasses.field(default_factory=Future)
     t_enqueue: float = 0.0
-    #: the batch trace id this request dispatched under (stamped by the
-    #: frontend when tracing is on) — the exemplar key that links a bad
-    #: latency observation to its Perfetto timeline
-    trace_id: str = ""
+    #: the shard-local sequence number of the batch the micro-batcher
+    #: put it in (stamped when the batch forms): every stage span of the
+    #: batch carries it as ``batch=``, and it keys the latency windows'
+    #: exemplars, so a bad quantile names a batch a profile shows
+    batch: int = -1
 
     def expired(self, now: float) -> bool:
         return self.deadline is not None and now > self.deadline

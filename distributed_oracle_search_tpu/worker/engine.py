@@ -55,7 +55,19 @@ M_RECEIVE = obs_metrics.histogram(
 M_WEIGHTS = obs_metrics.histogram(
     "worker_weights_load_seconds", "diff read + device weight upload")
 M_SEARCH = obs_metrics.histogram(
-    "worker_search_seconds", "steady-state search call (t_astar)")
+    "worker_search_seconds",
+    "steady-state search call (t_astar): walk launch until the device "
+    "finished; the answers' fetch to the host comes after it "
+    "(worker_fetch_seconds)")
+M_FETCH = obs_metrics.histogram(
+    "worker_fetch_seconds",
+    "table-search answers to the host in request order after the "
+    "walk: device slices, transfers, unsort, fan-out (outside t_astar "
+    "and t_search)")
+M_DEVICE_GAP = obs_metrics.histogram(
+    "worker_device_gap_seconds",
+    "table-search batches after an engine's first: host time from the "
+    "previous batch's answers on the host to this batch's walk launch")
 M_JIT = obs_metrics.histogram(
     "worker_jit_compile_seconds",
     "first call at a new (alg, shape, knobs) key — XLA compile + run, "
@@ -285,6 +297,9 @@ class ShardEngine:
         #: recorded to ``worker_jit_compile_seconds`` instead of the
         #: steady-state ``worker_search_seconds`` histogram
         self._jit_seen: set[tuple] = set()
+        #: when the previous table-search batch's answers reached the
+        #: host (``worker_device_gap_seconds`` runs from it)
+        self._t_answered: float | None = None
         #: device-resident graph arrays for the batched A* serving path
         #: (in-ELL, coords, per-diff padded weights) — uploaded once, not
         #: per request (ops.batched_astar ctx contract)
@@ -475,78 +490,79 @@ class ShardEngine:
 
         set_worker_id(self.wid)
         t0 = time.perf_counter()
-        self.last_paths = None
-        queries = np.asarray(queries, np.int64).reshape(-1, 2)
-        # routing invariant FIRST — before any shard-local row lookup,
-        # so a misrouted query fails with this diagnostic instead of an
-        # opaque index/shape error out of owned_index_of or the kernel
-        if len(queries):
-            owner = self.dc.worker_of(queries[:, 1])
-            if (owner != self.shard).any():
-                bad = int((owner != self.shard).sum())
-                raise ValueError(
-                    f"shard w{self.shard} received {bad} queries for "
-                    "other workers — routing invariant violated")
-        with obs_trace.span("worker.weights", wid=self.wid,
-                            difffile=difffile):
-            w_pad = self._weights_for(difffile, config.no_cache)
-        # the first-move table is epoch-gated per batch: the promoted
-        # delta index serves ONLY the epoch whose fused diff the batch
-        # names; everything else keeps the base table (see _fm_for)
-        fm_tbl = self._fm_for(difffile)
-        M_WEIGHTS.observe(time.perf_counter() - t0)
-        nq = len(queries)
-        if nq == 0:
-            if config.extract and config.k_moves > 0:
-                self.last_paths = (
-                    np.zeros((0, config.k_moves + 1), np.int64),
-                    np.zeros(0, np.int64))
-            elif config.sig_k > 0:
-                self.last_paths = (
-                    np.zeros((0, config.sig_k + 1), np.int64),
-                    np.zeros(0, np.int64))
-            return (np.zeros(0, np.int64), np.zeros(0, np.int64),
-                    np.zeros(0, bool), StatsRow())
-        # dedupe identical (s, t) pairs: skewed/online traffic repeats
-        # pairs, and the kernel only needs each distinct pair once —
-        # answers fan back out through `inverse`, the same machinery
-        # as the length-sort's `unsort` below. The A* path keeps the raw
-        # batch (its per-query deadline semantics and priority-queue
-        # counters measure the work actually done).
-        if self.alg == "astar":
-            uniq, inverse = queries, None
-        else:
-            uniq, inverse = np.unique(queries, axis=0,
-                                      return_inverse=True)
-            inverse = inverse.reshape(-1)
-            if len(uniq) < nq:
-                M_DUPS.inc(nq - len(uniq))
-        nu = len(uniq)
-        # order by expected walk length so the kernel's bucketed
-        # while_loops exit early (the same trick as CPDOracle.route;
-        # answers are unsorted back before returning)
-        from ..models.cpd import length_estimate
+        with obs_trace.span("worker.prep"):
+            self.last_paths = None
+            queries = np.asarray(queries, np.int64).reshape(-1, 2)
+            # routing invariant FIRST — before any shard-local row lookup,
+            # so a misrouted query fails with this diagnostic instead of an
+            # opaque index/shape error out of owned_index_of or the kernel
+            if len(queries):
+                owner = self.dc.worker_of(queries[:, 1])
+                if (owner != self.shard).any():
+                    bad = int((owner != self.shard).sum())
+                    raise ValueError(
+                        f"shard w{self.shard} received {bad} queries for "
+                        "other workers — routing invariant violated")
+            with obs_trace.span("worker.weights", wid=self.wid,
+                                difffile=difffile):
+                w_pad = self._weights_for(difffile, config.no_cache)
+            # the first-move table is epoch-gated per batch: the promoted
+            # delta index serves ONLY the epoch whose fused diff the batch
+            # names; everything else keeps the base table (see _fm_for)
+            fm_tbl = self._fm_for(difffile)
+            M_WEIGHTS.observe(time.perf_counter() - t0)
+            nq = len(queries)
+            if nq == 0:
+                if config.extract and config.k_moves > 0:
+                    self.last_paths = (
+                        np.zeros((0, config.k_moves + 1), np.int64),
+                        np.zeros(0, np.int64))
+                elif config.sig_k > 0:
+                    self.last_paths = (
+                        np.zeros((0, config.sig_k + 1), np.int64),
+                        np.zeros(0, np.int64))
+                return (np.zeros(0, np.int64), np.zeros(0, np.int64),
+                        np.zeros(0, bool), StatsRow())
+            # dedupe identical (s, t) pairs: skewed/online traffic repeats
+            # pairs, and the kernel only needs each distinct pair once —
+            # answers fan back out through `inverse`, the same machinery
+            # as the length-sort's `unsort` below. The A* path keeps the raw
+            # batch (its per-query deadline semantics and priority-queue
+            # counters measure the work actually done).
+            if self.alg == "astar":
+                uniq, inverse = queries, None
+            else:
+                uniq, inverse = np.unique(queries, axis=0,
+                                          return_inverse=True)
+                inverse = inverse.reshape(-1)
+                if len(uniq) < nq:
+                    M_DUPS.inc(nq - len(uniq))
+            nu = len(uniq)
+            # order by expected walk length so the kernel's bucketed
+            # while_loops exit early (the same trick as CPDOracle.route;
+            # answers are unsorted back before returning)
+            from ..models.cpd import length_estimate
 
-        order = np.argsort(
-            length_estimate(self.graph, uniq[:, 0], uniq[:, 1]),
-            kind="stable")
-        unsort = np.argsort(order)
-        qsorted = uniq[order]
-        # pad to the next power of two: stable shapes, no recompiles as the
-        # per-worker batch size shifts between campaigns. A lane-mesh
-        # engine pads at least to the lane count so EVERY batch splits
-        # evenly over the mesh (the extra rows are valid=False lanes)
-        qpad = 1 << (nu - 1).bit_length()
-        if self._lane_split:
-            qpad = max(qpad, self.n_lanes)
-        s = np.zeros(qpad, np.int32)
-        t = np.zeros(qpad, np.int32)
-        valid = np.zeros(qpad, bool)
-        s[:nu] = qsorted[:, 0]
-        t[:nu] = qsorted[:, 1]
-        valid[:nu] = True
-        rows = np.zeros(qpad, np.int32)
-        rows[:nu] = self.dc.owned_index_of(qsorted[:, 1])
+            order = np.argsort(
+                length_estimate(self.graph, uniq[:, 0], uniq[:, 1]),
+                kind="stable")
+            unsort = np.argsort(order)
+            qsorted = uniq[order]
+            # pad to the next power of two: stable shapes, no recompiles as the
+            # per-worker batch size shifts between campaigns. A lane-mesh
+            # engine pads at least to the lane count so EVERY batch splits
+            # evenly over the mesh (the extra rows are valid=False lanes)
+            qpad = 1 << (nu - 1).bit_length()
+            if self._lane_split:
+                qpad = max(qpad, self.n_lanes)
+            s = np.zeros(qpad, np.int32)
+            t = np.zeros(qpad, np.int32)
+            valid = np.zeros(qpad, bool)
+            s[:nu] = qsorted[:, 0]
+            t[:nu] = qsorted[:, 1]
+            valid[:nu] = True
+            rows = np.zeros(qpad, np.int32)
+            rows[:nu] = self.dc.owned_index_of(qsorted[:, 1])
 
         t1 = time.perf_counter()
         M_RECEIVE.observe(t1 - t0)
@@ -650,11 +666,12 @@ class ShardEngine:
         first_call = jit_key not in self._jit_seen
         if self.alg == "astar":
             deadline = t1 + config.time / 1e9 if config.time else None
-            for _ in range(max(config.itrs, 1)):
-                cost, plen, fin, counters = self._answer_astar(
-                    queries, config, difffile, deadline=deadline)
-                if deadline is not None and time.perf_counter() > deadline:
-                    break
+            with obs_trace.span("worker.walk"):
+                for _ in range(max(config.itrs, 1)):
+                    cost, plen, fin, counters = self._answer_astar(
+                        queries, config, difffile, deadline=deadline)
+                    if deadline is not None and time.perf_counter() > deadline:
+                        break
             t2 = time.perf_counter()
             self._finish_search(jit_key, first_call, nq, t2 - t1)
             stats = StatsRow(
@@ -680,70 +697,75 @@ class ShardEngine:
                 k_moves=config.k_moves)
 
         deadline = t1 + config.time / 1e9 if config.time else None
-        for _ in range(max(config.itrs, 1)):
-            if deadline is None or qpad <= self.astar_chunk:
-                cost, plen, fin = run_walk(rows, s, t, valid)
-                jax.block_until_ready(fin)
-            else:
-                # ns budget truncates INSIDE the batch (reference
-                # semantics: the time limit cuts searches short in the
-                # engine, reference args.py:30-57): the sorted batch
-                # runs in fixed-size chunks — cheap queries first — and
-                # the deadline is checked between chunks. The first
-                # chunk always runs (an expired budget still yields a
-                # minimal answer, same rule as the A* chunk path);
-                # skipped chunks come back unfinished, so `finished`
-                # counts are partial like the reference's.
-                ch = self.astar_chunk         # pow2, divides qpad
-                cost, plen, fin = (np.zeros(qpad, np.int64),
-                                   np.zeros(qpad, np.int64),
-                                   np.zeros(qpad, bool))
-                # one chunk stays in flight ahead (dispatch k+1, then
-                # block on k): a generous budget keeps most of the
-                # single-call pipelining; truncation granularity is one
-                # extra chunk at worst
-                pending = None       # (slice, async device triple)
+        if self._t_answered is not None:
+            # host time since the previous batch's answers: nothing of
+            # this engine's was queued on its device in it
+            M_DEVICE_GAP.observe(t1 - self._t_answered)
+        with obs_trace.span("worker.walk"):
+            for _ in range(max(config.itrs, 1)):
+                if deadline is None or qpad <= self.astar_chunk:
+                    cost, plen, fin = run_walk(rows, s, t, valid)
+                    jax.block_until_ready(fin)
+                else:
+                    # ns budget truncates INSIDE the batch (reference
+                    # semantics: the time limit cuts searches short in the
+                    # engine, reference args.py:30-57): the sorted batch
+                    # runs in fixed-size chunks — cheap queries first — and
+                    # the deadline is checked between chunks. The first
+                    # chunk always runs (an expired budget still yields a
+                    # minimal answer, same rule as the A* chunk path);
+                    # skipped chunks come back unfinished, so `finished`
+                    # counts are partial like the reference's.
+                    ch = self.astar_chunk         # pow2, divides qpad
+                    cost, plen, fin = (np.zeros(qpad, np.int64),
+                                       np.zeros(qpad, np.int64),
+                                       np.zeros(qpad, bool))
+                    # one chunk stays in flight ahead (dispatch k+1, then
+                    # block on k): a generous budget keeps most of the
+                    # single-call pipelining; truncation granularity is one
+                    # extra chunk at worst
+                    pending = None       # (slice, async device triple)
 
-                def _land(entry):
-                    sl_p, (c_p, p_p, f_p) = entry
-                    jax.block_until_ready(f_p)
-                    cost[sl_p], plen[sl_p], fin[sl_p] = (
-                        np.asarray(c_p), np.asarray(p_p), np.asarray(f_p))
-                for off in range(0, qpad, ch):
-                    if off and time.perf_counter() > deadline:
-                        break
-                    sl = slice(off, off + ch)
-                    outs = run_walk(rows[sl], s[sl], t[sl], valid[sl])
+                    def _land(entry):
+                        sl_p, (c_p, p_p, f_p) = entry
+                        jax.block_until_ready(f_p)
+                        cost[sl_p], plen[sl_p], fin[sl_p] = (
+                            np.asarray(c_p), np.asarray(p_p), np.asarray(f_p))
+                    for off in range(0, qpad, ch):
+                        if off and time.perf_counter() > deadline:
+                            break
+                        sl = slice(off, off + ch)
+                        outs = run_walk(rows[sl], s[sl], t[sl], valid[sl])
+                        if pending is not None:
+                            _land(pending)
+                        pending = (sl, outs)
                     if pending is not None:
                         _land(pending)
-                    pending = (sl, outs)
-                if pending is not None:
-                    _land(pending)
-            if deadline is not None and time.perf_counter() > deadline:
-                break
-        if config.extract and config.k_moves > 0:
-            nodes, moves = extract_paths(
-                self.dg, fm_walk, jnp.asarray(rows), jnp.asarray(s),
-                jnp.asarray(t), k=config.k_moves)
-            nodes = np.asarray(nodes[:nu], np.int64)[unsort]
-            moves = np.asarray(moves[:nu], np.int64)[unsort]
-            if inverse is not None:
-                nodes, moves = nodes[inverse], moves[inverse]
-            self.last_paths = (nodes, moves)
-        elif config.sig_k > 0:
-            # bounded path SIGNATURE for the serving cache's scoped
-            # invalidation (RuntimeConfig.sig_k wire extension): the
-            # same extraction scan as --extract but decoupled from
-            # k_moves, so the walk's move budget — and therefore every
-            # answer — is untouched
-            nodes, moves = extract_paths(
-                self.dg, fm_walk, jnp.asarray(rows), jnp.asarray(s),
-                jnp.asarray(t), k=int(config.sig_k))
-            nodes = np.asarray(nodes[:nu], np.int64)[unsort]
-            moves = np.asarray(moves[:nu], np.int64)[unsort]
-            if inverse is not None:
-                nodes, moves = nodes[inverse], moves[inverse]
-            self.last_paths = (nodes, moves)
+                if deadline is not None and time.perf_counter() > deadline:
+                    break
+            if config.extract and config.k_moves > 0:
+                nodes, moves = extract_paths(
+                    self.dg, fm_walk, jnp.asarray(rows), jnp.asarray(s),
+                    jnp.asarray(t), k=config.k_moves)
+                nodes = np.asarray(nodes[:nu], np.int64)[unsort]
+                moves = np.asarray(moves[:nu], np.int64)[unsort]
+                if inverse is not None:
+                    nodes, moves = nodes[inverse], moves[inverse]
+                self.last_paths = (nodes, moves)
+            elif config.sig_k > 0:
+                # bounded path SIGNATURE for the serving cache's scoped
+                # invalidation (RuntimeConfig.sig_k wire extension): the
+                # same extraction scan as --extract but decoupled from
+                # k_moves, so the walk's move budget — and therefore every
+                # answer — is untouched
+                nodes, moves = extract_paths(
+                    self.dg, fm_walk, jnp.asarray(rows), jnp.asarray(s),
+                    jnp.asarray(t), k=int(config.sig_k))
+                nodes = np.asarray(nodes[:nu], np.int64)[unsort]
+                moves = np.asarray(moves[:nu], np.int64)[unsort]
+                if inverse is not None:
+                    nodes, moves = nodes[inverse], moves[inverse]
+                self.last_paths = (nodes, moves)
         t2 = time.perf_counter()
         self._finish_search(jit_key, first_call, nq, t2 - t1)
         if first_call and obs_device.enabled():
@@ -805,14 +827,22 @@ class ShardEngine:
                     jnp.asarray(t[sl]), w_pad,
                     valid=jnp.asarray(valid[sl]), k_moves=config.k_moves)
 
-        cost = np.asarray(cost[:nu], np.int64)[unsort]
-        plen = np.asarray(plen[:nu], np.int64)[unsort]
-        fin = np.asarray(fin[:nu], bool)[unsort]
-        if inverse is not None:
-            # fan deduped answers back out to every original query —
-            # the stats sums below stay per ORIGINAL query by summing
-            # AFTER this expansion
-            cost, plen, fin = cost[inverse], plen[inverse], fin[inverse]
+        # the answers to the host in request order: three device slices,
+        # their transfers, the unsort and the fan-out. The walk's
+        # interval (t_astar, worker_search_seconds) ends before this
+        tf = time.perf_counter()
+        with obs_trace.span("worker.fetch"):
+            cost = np.asarray(cost[:nu], np.int64)[unsort]
+            plen = np.asarray(plen[:nu], np.int64)[unsort]
+            fin = np.asarray(fin[:nu], bool)[unsort]
+            if inverse is not None:
+                # fan deduped answers back out to every original query
+                # — the stats sums below stay per ORIGINAL query by
+                # summing AFTER this expansion
+                cost, plen, fin = (cost[inverse], plen[inverse],
+                                   fin[inverse])
+        self._t_answered = time.perf_counter()
+        M_FETCH.observe(self._t_answered - tf)
         stats = StatsRow(
             n_expanded=int(plen.sum()),   # node expansions = moves walked
             n_touched=nq,
@@ -828,7 +858,7 @@ class ShardEngine:
                        seconds: float) -> None:
         """Book one batch's search interval: first call at a new program
         key goes to the compile histogram (XLA compilation dominates it),
-        repeats to the steady-state one; the span mirrors the split."""
+        repeats to the steady-state one."""
         self._jit_seen.add(jit_key)
         (M_JIT if first_call else M_SEARCH).observe(seconds)
         if not first_call:
@@ -840,9 +870,6 @@ class ShardEngine:
                 trace_id=obs_trace.current_trace_id())
         M_BATCHES.inc()
         M_QUERIES.inc(nq)
-        obs_trace.add_span("worker.search", seconds, wid=self.wid,
-                           alg=self.alg, queries=nq,
-                           first_call=first_call)
 
     def _raw_weights_for(self, difffile: str, no_cache: bool):
         """Raw (unpadded) query weights + heuristic scale, cached per diff

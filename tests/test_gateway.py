@@ -265,6 +265,12 @@ def test_gateway_busy_at_credit_window(tmp_path):
         for fid in range(3):
             h, a = protocol.encode_pairs(fid, [(1, 2)])
             writer.send(h, a)
+        # release the engine only once the reader has refused the third
+        # frame: released earlier, a reply could free a credit before
+        # the reader got to it
+        deadline = time.monotonic() + 30.0
+        while srv.busy < 1 and time.monotonic() < deadline:
+            time.sleep(0.005)
         release.set()
         kinds = {}
         for _ in range(3):

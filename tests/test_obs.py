@@ -130,13 +130,16 @@ def test_counter_thread_safety():
 # -------------------------------------------------------------------- trace
 
 def test_span_disabled_is_shared_noop():
+    """Chrome collection off and no profile recording: one shared null
+    object, no allocation, nothing recorded — tags included."""
     assert not obs_trace.enabled()
     s1 = obs_trace.span("a", k=1)
     s2 = obs_trace.span("b")
-    assert s1 is s2                 # one shared null object, no allocs
+    with obs_trace.tagged(batch=3, size=2):
+        s3 = obs_trace.span("c")
+    assert s1 is s2 is s3           # one shared null object, no allocs
     with s1:
         pass
-    obs_trace.add_span("c", 0.5)
     assert obs_trace.events() == []
 
 
@@ -146,16 +149,21 @@ def test_span_records_chrome_events_with_trace_id():
     with obs_trace.span("outer", wid=3):
         with obs_trace.span("inner"):
             time.sleep(0.002)
-    obs_trace.add_span("measured", 0.25, wid=3)
+    with obs_trace.tagged(batch=7, size=2):
+        with obs_trace.span("tagged", wid=3, size=5):
+            time.sleep(0.001)
     evs = obs_trace.events()
-    assert [e["name"] for e in evs] == ["inner", "outer", "measured"]
+    assert [e["name"] for e in evs] == ["inner", "outer", "tagged"]
     for e in evs:
         assert e["ph"] == "X" and e["pid"] == os.getpid()
         assert e["args"]["trace_id"] == "tid-1"
-    inner, outer, measured = evs
+    inner, outer, tagged = evs
     assert inner["dur"] >= 2000          # us
     assert outer["dur"] >= inner["dur"]
-    assert measured["dur"] == 250000
+    # the thread's tags ride along; an explicit argument wins
+    assert tagged["dur"] >= 1000
+    assert (tagged["args"]["batch"], tagged["args"]["size"],
+            tagged["args"]["wid"]) == (7, 5, 3)
     # explicit trace_id overrides the thread's
     with obs_trace.span("explicit", trace_id="other"):
         pass
@@ -557,12 +565,12 @@ def test_traced_campaign_end_to_end(obs_cluster, tmp_path, monkeypatch):
     evs = doc["traceEvents"]
     names = {e["name"] for e in evs}
     assert {"head.read", "head.partition", "head.prepare", "head.send",
-            "worker.receive", "worker.weights",
-            "worker.search"} <= names
+            "worker.receive", "worker.prep", "worker.weights",
+            "worker.walk", "worker.fetch"} <= names
     sends = {e["args"]["trace_id"]: e for e in evs
              if e["name"] == "head.send"}
     searches = {e["args"]["trace_id"]: e for e in evs
-                if e["name"] == "worker.search"}
+                if e["name"] == "worker.walk"}
     shared = set(sends) & set(searches)
     # every batch (2 workers x 2 diff rounds) joined head<->worker
     assert len(shared) == conf.maxworker * len(conf.diffs)

@@ -333,14 +333,18 @@ def test_bench_numbers_survives_null_tail(tmp_path):
 
 
 def test_exemplar_trace_id_propagates_from_traced_dispatch():
-    """With tracing on, every dispatched batch gets a trace id; the
-    window's worst request exposes it — the p99 -> Perfetto link."""
+    """Every dispatched batch carries its shard-local number, tracing
+    on or off; the windows' worst request and worst dispatch name it
+    (``w<shard>.b<n>``) — the p99 -> profile link."""
     obs_quantiles.WINDOWS.reset()
-    obs_trace.enable()
-    seen_rconf_ids = []
+    seen_batches = []
 
     def fn(wid, q, rconf, diff):
-        seen_rconf_ids.append(rconf.trace_id)
+        # the runner dispatches inside the batch's tags: every span the
+        # engine opens here would carry them
+        tags = obs_trace.current_tags()
+        assert tags["size"] == len(q)
+        seen_batches.append(tags["batch"])
         n = len(q)
         return (np.zeros(n, np.int64), np.zeros(n, np.int64),
                 np.ones(n, bool))
@@ -356,13 +360,13 @@ def test_exemplar_trace_id_propagates_from_traced_dispatch():
             assert fe.query(i, i + 1, timeout=30).ok
     finally:
         fe.stop()
-        obs_trace.enable(False)
-    # the wire saw per-batch ids (the worker would capture spans under
-    # them) ...
-    assert seen_rconf_ids and all(seen_rconf_ids)
-    worst = obs_quantiles.WINDOWS.window("serve_request_seconds").worst()
-    # ... and the window's exemplar is one of those SAME ids
-    assert worst is not None and worst[1] in set(seen_rconf_ids)
+    # batches are numbered in order from 0 on the shard ...
+    assert seen_batches == list(range(len(seen_batches)))
+    ids = {f"w0.b{b}" for b in seen_batches}
+    # ... and each window's exemplar is one of those SAME batches
+    for name in ("serve_request_seconds", "serve_dispatch_seconds"):
+        worst = obs_quantiles.WINDOWS.window(name).worst()
+        assert worst is not None and worst[1] in ids
 
 
 # ---------------------------------------------------------- fleet merge
