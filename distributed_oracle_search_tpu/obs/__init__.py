@@ -123,11 +123,11 @@ admission decision, batch, and cache outcome is visible):
   ``worker_duplicate_queries_total``;
 * stage waits and spans (every batch carries a shard-local number,
   ``batch=`` on each span below) — ``serve_queue_wait_seconds`` (each
-  request, enqueued until popped into a batch; span ``serve.collect``
-  on the collector), ``serve_handoff_wait_seconds`` (each batch,
-  flushed until the runner takes it from the depth-1 handoff; spans
-  ``serve.handoff``, the collector's blocked put, and ``serve.wait``,
-  the runner's empty get), then on the runner ``serve.dispatch``
+  request, enqueued until the shard's runner pops it into a batch;
+  span ``serve.wait``, the runner waiting in ``get_batch``),
+  ``serve_handoff_wait_seconds`` (each batch, flushed until its
+  dispatch starts: the runner's own bookkeeping, microseconds), then
+  on the runner ``serve.dispatch``
   around ``worker.prep`` (``worker_receive_seconds``, with
   ``worker.weights`` nested), ``worker.walk``
   (``worker_search_seconds``) and ``worker.fetch``

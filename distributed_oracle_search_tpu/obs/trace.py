@@ -2,8 +2,8 @@
 trace-event JSON.
 
 The timing half of the observability layer (``obs/``): phases of a query
-batch — head-side prepare/partition/send, the serving path's collect,
-handoff, prep, walk, fetch and finish, the gateway's frame and reply —
+batch — head-side prepare/partition/send, the serving path's wait,
+prep, walk, fetch and finish, the gateway's frame and reply —
 run inside :func:`span` context managers. One span feeds up to two
 sinks:
 

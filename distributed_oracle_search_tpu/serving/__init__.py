@@ -16,11 +16,12 @@ resilience, and observability layers the campaign path already uses:
 * :class:`~.queue.ShardQueue` — bounded per-shard request queue with
   per-request deadlines (expired requests complete ``TIMEOUT``);
 * :class:`~.batcher.MicroBatcher` — per-shard adaptive micro-batcher:
-  flushes when the batch hits the power-of-two ``max_batch`` (so
+  one thread per shard forms a batch when it is free to run it,
+  flushing when the batch hits the power-of-two ``max_batch`` (so
   workers reuse the handful of compiled programs ``ShardEngine`` keys
-  on ``qpad``) or when ``max_wait_ms`` elapses, and keeps exactly ONE
-  batch in flight per shard so host-side batch forming pipelines with
-  device execution;
+  on ``qpad``) or when ``max_wait_ms`` elapses; what arrives during a
+  dispatch waits in the queue and forms the next batch, so batch size
+  tracks load;
 * :class:`~.cache.ResultCache` — bounded LRU keyed on
   ``(s, t, diff, knob fingerprint)``, short-circuiting repeats on
   skewed traffic; invalidated on diff change;

@@ -1,35 +1,37 @@
-"""Per-shard adaptive micro-batcher: two threads, one batch in flight.
+"""Per-shard adaptive micro-batcher: one thread forms a batch when it
+is free to run it.
 
-The **collector** thread forms batches off the shard's
-:class:`~.queue.ShardQueue` (flush at the power-of-two ``max_batch`` or
-on ``max_wait_ms`` expiry) and hands them through a depth-1 queue to the
-**runner** thread, which executes the dispatch callback. The depth-1
-handoff is the pipelining contract: exactly ONE batch is in flight on
-the shard while the collector is already forming (and the frontend's
-dispatch callback is host-prepping) the next — and when the shard falls
-behind, the handoff's backpressure makes waiting batches grow toward
-``max_batch`` instead of racing out as singletons, which is what makes
-the batching *adaptive*: batch size tracks load.
+The shard's one thread (the **runner**) loops: take the next batch off
+the shard's :class:`~.queue.ShardQueue` with ``get_batch`` (flush at the
+power-of-two ``max_batch`` or on ``max_wait_ms`` expiry), then run the
+dispatch callback on it. No batch forms ahead of the runner: requests
+that arrive while a batch is in flight stay in the queue, and when the
+runner frees it pops them at once (their oldest has already waited past
+``max_wait_ms``, so ``get_batch`` flushes immediately, up to
+``max_batch``). That is what makes the batching *adaptive*: under load
+the batch is what arrived during the last dispatch, so batch size
+tracks load; at light load a batch is its first request plus whatever
+comes within ``max_wait_ms``. The dispatch is synchronous for every
+backend, so a batch formed earlier could only wait; and the queue's
+``queue_depth`` bounds every request waiting on the shard.
 
 Each batch gets a shard-local sequence number when it forms, stamped on
-its requests (``ServeRequest.batch``). Every stage span carries it as
-``batch=`` — ``serve.collect`` (collector, in ``get_batch``),
-``serve.handoff`` (collector, blocked on the full handoff),
-``serve.wait`` (runner, waiting on an empty handoff) — and the runner
+its requests (``ServeRequest.batch``). The runner's wait for a batch
+(``get_batch``) is the span ``serve.wait`` with ``batch=``, and it
 dispatches inside :func:`obs.trace.tagged` ``(batch=, size=)``, so the
 frontend's and the engine's spans name the same batch. The histograms
 ``serve_queue_wait_seconds`` (each request, enqueued until popped into
 a batch) and ``serve_handoff_wait_seconds`` (each batch, flushed until
-the runner takes it) time the two waits in front of a dispatch.
+its dispatch starts: the runner's own bookkeeping, microseconds) time
+the waits in front of a dispatch.
 
-Threads are named ``dos-serve-*`` — the test suite's leak check
+The thread is named ``dos-serve-*`` — the test suite's leak check
 (tests/conftest.py) holds every ``dos-*`` thread to the
-joined-on-shutdown contract, and :meth:`MicroBatcher.stop` joins both.
+joined-on-shutdown contract, and :meth:`MicroBatcher.stop` joins it.
 """
 
 from __future__ import annotations
 
-import queue as _stdqueue
 import threading
 import time
 
@@ -61,8 +63,7 @@ H_QUEUE_WAIT = obs_metrics.histogram(
     "each request, enqueued until popped into a batch")
 H_HANDOFF_WAIT = obs_metrics.histogram(
     "serve_handoff_wait_seconds",
-    "each batch, flushed until the runner takes it from the depth-1 "
-    "handoff")
+    "each batch, flushed until the runner starts its dispatch")
 H_DISPATCH = obs_metrics.histogram(
     "serve_dispatch_seconds", "batch dispatch (engine call or wire "
     "round-trip) as seen by the runner thread")
@@ -82,39 +83,36 @@ class MicroBatcher:
         self.dispatch = dispatch
         self.max_batch = int(max_batch)
         self.max_wait_s = float(max_wait_s)
-        self._handoff: _stdqueue.Queue = _stdqueue.Queue(maxsize=1)
         self._stop = threading.Event()
         #: THIS batcher's dispatch-in-progress flag — stop() must drain
         #: on it, not on the process-global in-flight gauge, or one busy
         #: shard (or a second frontend) would stall every other shard's
         #: shutdown for the full drain budget
         self._dispatching = False
-        self._collector = threading.Thread(
-            target=self._collect_loop, daemon=True,
-            name=f"dos-serve-collect-w{wid}")
         self._runner = threading.Thread(
             target=self._run_loop, daemon=True,
             name=f"dos-serve-dispatch-w{wid}")
 
     def start(self) -> None:
-        self._collector.start()
         self._runner.start()
 
-    # ---------------------------------------------------------- threads
-    def _collect_loop(self) -> None:
+    # ----------------------------------------------------------- thread
+    def _run_loop(self) -> None:
         seq = 0
-        while True:
-            with obs_trace.span("serve.collect", shard=self.wid,
-                                batch=seq):
+        # once stop() gave up draining, what is still queued is its to
+        # fail: take no further batch
+        while not self._stop.is_set():
+            with obs_trace.span("serve.wait", shard=self.wid, batch=seq):
                 batch = self.queue.get_batch(self.max_batch,
                                              self.max_wait_s, self._stop)
             if not batch:
                 # a closed, drained queue is terminal (try_put refuses
                 # once closed): exit instead of spinning on instant
                 # empty get_batch returns until stop() gets to us
-                if self._stop.is_set() or self.queue.closed:
+                if self.queue.closed:
                     return
                 continue
+            self._dispatching = True
             flushed = time.monotonic()
             for r in batch:
                 r.batch = seq
@@ -123,33 +121,7 @@ class MicroBatcher:
             H_FLUSH.observe(flushed - batch[0].t_enqueue)
             (M_FLUSH_FULL if len(batch) >= self.max_batch
              else M_FLUSH_WAIT).inc()
-            with obs_trace.span("serve.handoff", shard=self.wid,
-                                batch=seq, size=len(batch)):
-                while True:
-                    try:
-                        self._handoff.put((seq, flushed, batch),
-                                          timeout=_HANDOFF_TICK_S)
-                        break
-                    except _stdqueue.Full:
-                        if self._stop.is_set():
-                            _fail_batch(batch, "shutdown")
-                            return
-            seq += 1
-
-    def _run_loop(self) -> None:
-        seq = 0
-        while True:
-            with obs_trace.span("serve.wait", shard=self.wid, batch=seq):
-                while True:
-                    try:
-                        seq, flushed, batch = self._handoff.get(
-                            timeout=_HANDOFF_TICK_S)
-                        break
-                    except _stdqueue.Empty:
-                        if self._stop.is_set():
-                            return
             H_HANDOFF_WAIT.observe(time.monotonic() - flushed)
-            self._dispatching = True
             G_INFLIGHT.add(1)
             t0 = time.perf_counter()
             try:
@@ -170,29 +142,18 @@ class MicroBatcher:
     # --------------------------------------------------------- shutdown
     def stop(self, drain_s: float = 5.0) -> None:
         """Close the queue, give in-flight/queued work ``drain_s`` to
-        finish, then stop both threads and fail anything left — every
+        finish, then stop the runner and fail anything left — every
         admitted request still terminates."""
         self.queue.close()
         deadline = time.monotonic() + max(drain_s, 0.0)
         while time.monotonic() < deadline:
-            if (len(self.queue) == 0 and self._handoff.empty()
-                    and not self._dispatching):
+            if len(self.queue) == 0 and not self._dispatching:
                 break
             time.sleep(0.01)
         self._stop.set()
-        for t in (self._collector, self._runner):
-            if t.is_alive():
-                t.join(timeout=drain_s + 1.0)
+        if self._runner.is_alive():
+            self._runner.join(timeout=drain_s + 1.0)
         _fail_batch(self.queue.drain(), "shutdown")
-        while True:
-            try:
-                _fail_batch(self._handoff.get_nowait()[2], "shutdown")
-            except _stdqueue.Empty:
-                break
-
-
-#: wakeup tick for the depth-1 handoff waits (stop-signal latency bound)
-_HANDOFF_TICK_S = 0.05
 
 
 def _fail_batch(batch: list[ServeRequest], detail: str) -> None:

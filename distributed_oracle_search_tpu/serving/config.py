@@ -20,9 +20,11 @@ log = get_logger(__name__)
 class ServeConfig:
     """Online-serving tunables.
 
-    * ``queue_depth`` — bound of each shard's request queue; a full
-      queue sheds ``BUSY`` immediately (admission control, never a
-      silent hang). Env: ``DOS_SERVE_QUEUE_DEPTH``.
+    * ``queue_depth`` — bound of each shard's request queue, which
+      holds every request waiting on the shard (no batch forms before
+      the shard's runner is free to dispatch it); a full queue sheds
+      ``BUSY`` immediately (admission control, never a silent hang).
+      Env: ``DOS_SERVE_QUEUE_DEPTH``.
     * ``max_batch`` — flush threshold of the micro-batcher. MUST be a
       power of two: batches pad to the next power of two inside
       ``ShardEngine.answer``, so a pow2 cap means steady-state traffic

@@ -14,6 +14,7 @@ import os
 import socket
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -44,9 +45,8 @@ from distributed_oracle_search_tpu.worker import engine as wk_engine
 from distributed_oracle_search_tpu.worker.build import main as build_main
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-#: the runner thread's leaf spans and the collector's, per batch
+#: the runner thread's leaf spans, per batch
 RUNNER = ("serve.wait", "serve.dispatch", "serve.finish")
-COLLECTOR = ("serve.collect", "serve.handoff")
 
 
 @pytest.fixture(autouse=True)
@@ -87,10 +87,9 @@ def _snap(hist):
 
 def test_queue_and_handoff_waits_per_request_and_per_batch():
     """Four single-request batches at once in front of an engine that
-    takes T each: the runner takes them at 0, T, 2T, 3T after flushes
-    at 0, 0, 0, T, so the handoff waits sum to 0 + T + 2T + 2T = 5T;
-    the fourth request waited T in the queue (the collector was blocked
-    handing off the third), the others none."""
+    takes T each: the runner pops them at 0, T, 2T, 3T, so the queue
+    waits sum to 0 + T + 2T + 3T = 6T, and each batch's dispatch starts
+    as soon as it is popped, so the handoff waits are next to nothing."""
     T = 0.2
     q0 = _snap(sv_batcher.H_QUEUE_WAIT)
     h0 = _snap(sv_batcher.H_HANDOFF_WAIT)
@@ -105,18 +104,18 @@ def test_queue_and_handoff_waits_per_request_and_per_batch():
     assert sorted(r.batch for r in res) == [0, 1, 2, 3]
     n, qsum = _delta(sv_batcher.H_QUEUE_WAIT, q0)
     assert n == 4                               # once per request
-    assert T * 0.9 <= qsum <= T + 0.15
+    assert 6 * T * 0.9 <= qsum <= 6 * T + 0.3
     n, hsum = _delta(sv_batcher.H_HANDOFF_WAIT, h0)
     assert n == 4                               # once per batch
-    assert 5 * T * 0.9 <= hsum <= 5 * T + 0.3
+    assert 0 <= hsum < 0.05
     n, dsum = _delta(sv_batcher.H_DISPATCH, d0)
     assert n == 4 and 4 * T <= dsum <= 4 * T + 0.3
 
 
 def test_batch_numbers_follow_the_batch_through_every_span():
-    """With Chrome collection on, the collector's and the runner's
-    spans of each batch carry the same ``batch=`` number, and the
-    runner's carry its size."""
+    """With Chrome collection on, the runner's spans of each batch
+    carry the same ``batch=`` number, and those after the batch formed
+    carry its size."""
     obs_trace.enable()
     fe = _frontend(_sleeper(0.01), max_batch=4)
     try:
@@ -132,11 +131,78 @@ def test_batch_numbers_follow_the_batch_through_every_span():
     evs = obs_trace.events()
     for b, size in by_batch.items():
         names = {e["name"] for e in evs if e["args"].get("batch") == b}
-        assert set(RUNNER + COLLECTOR) <= names, (b, names)
+        assert set(RUNNER) <= names, (b, names)
         for e in evs:
             if e["args"].get("batch") == b and e["name"] in (
-                    "serve.dispatch", "serve.finish", "serve.handoff"):
+                    "serve.dispatch", "serve.finish"):
                 assert e["args"]["size"] == size
+
+
+def _gated(entered: threading.Event, gate: threading.Event):
+    """A fake engine that flags its first call and holds every call
+    until ``gate`` opens."""
+    def answer(wid, q, rconf, diff):
+        entered.set()
+        assert gate.wait(30)
+        return _sleeper(0)(wid, q, rconf, diff)
+    return answer
+
+
+def test_nothing_forms_ahead_of_the_runner():
+    """While one batch is in dispatch, every later request is still in
+    the shard's queue (the queue bound covers them all): no batch forms
+    until the runner is free to run it. Released, they answer in order,
+    one batch each."""
+    entered, gate = threading.Event(), threading.Event()
+    fe = _frontend(_gated(entered, gate), max_batch=1, queue_depth=8)
+    try:
+        futs = [fe.submit(i, i + 1) for i in range(5)]
+        assert entered.wait(30)
+        queue = fe._queues[0]
+        assert len(queue) == 4
+        time.sleep(0.1)
+        assert len(queue) == 4
+        gate.set()
+        res = [f.result(30) for f in futs]
+    finally:
+        gate.set()
+        fe.stop()
+    assert all(r.ok for r in res)
+    assert [r.cost for r in res] == [1] * 5
+    assert [r.batch for r in res] == [0, 1, 2, 3, 4]
+
+
+def test_stop_fails_the_queued_requests_and_joins_the_runner():
+    """``stop`` with a batch in dispatch and four requests queued past
+    its drain budget: the batch in flight answers once stop gave up
+    draining, the queued ones complete ``shutdown`` errors, and the
+    shard's thread is gone."""
+    entered, gate = threading.Event(), threading.Event()
+    before = {t.name for t in threading.enumerate()}
+    fe = _frontend(_gated(entered, gate), max_batch=1, queue_depth=8)
+    futs = [fe.submit(i, i + 1) for i in range(5)]
+    assert entered.wait(30)
+    stopping = fe._batchers[0]._stop
+
+    def open_when_stopping():
+        stopping.wait(30)
+        gate.set()
+
+    opener = threading.Thread(target=open_when_stopping)
+    opener.start()
+    try:
+        fe.stop(drain_s=0.1)
+    finally:
+        gate.set()
+        opener.join()
+    assert all(f.done() for f in futs)
+    res = [f.result(0) for f in futs]
+    assert res[0].ok
+    assert [(r.status, r.detail) for r in res[1:]] == [
+        ("ERROR", "shutdown")] * 4
+    assert not [t.name for t in threading.enumerate()
+                if t.name.startswith("dos-serve-") and t.is_alive()
+                and t.name not in before]
 
 
 # ------------------------------------------------------------ engine
@@ -306,7 +372,7 @@ def test_span_lands_on_the_profilers_host_plane(tmp_path):
     evs = _host_events(str(tmp_path))
     walk = [st for name, st in evs if name == "worker.walk"]
     assert walk and walk[0]["batch"] == 41 and walk[0]["size"] == 3
-    for name in RUNNER + COLLECTOR:
+    for name in RUNNER:
         got = [st for n, st in evs if n == name
                and st.get("batch") == res.batch]
         assert got, name
