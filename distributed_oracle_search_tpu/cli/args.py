@@ -38,7 +38,9 @@ def build_parser(prog: str | None = None) -> argparse.ArgumentParser:
     part.add_argument("-p", "--num-partitions", type=int, default=0,
                       help="Number of partitions (0 = one per worker).")
     part.add_argument("-s", "--size-partitions", type=int, default=0,
-                      help="Target partition size (overrides -p).")
+                      help="Target partition size (overrides -p); the "
+                           "TPU campaign answers each round in parts of "
+                           "this many pairs (0 = one call).")
     part.add_argument("--group", type=str,
                       choices=["all", "mod", "div"],
                       help="Partition generation scheme; default is by "

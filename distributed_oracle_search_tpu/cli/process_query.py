@@ -8,10 +8,13 @@ campaign artifacts.
 Two backends behind one stats schema:
 
 * ``partmethod=tpu`` — the north-star path: the CPD lives sharded on a
-  device mesh; each diff round is answered by ONE sharded XLA call
-  (``CPDOracle.query``) instead of N FIFO round-trips. Per-worker stats
-  rows are recovered from the routed results, so downstream tooling sees
-  the same ``parts.csv`` either way.
+  device mesh; each diff round is answered by sharded XLA calls
+  (``CPDOracle.query``) instead of N FIFO round-trips: ONE call over the
+  whole scenario by default, or, with ``-s P``, one call per part of P
+  pairs in scenario order over the index loaded once (every routed
+  width the parts produce is compiled before the first part). Per-worker
+  stats rows are recovered from the routed results, so downstream
+  tooling sees the same ``parts.csv`` either way.
 * host mode — the reference mechanism, modernized: query files to the
   shared dir, 2-line config through each worker's command FIFO, one CSV
   stats line back (``transport``), driven concurrently by a thread pool
@@ -22,6 +25,8 @@ Artifacts (``-o DIR``): ``metrics.json`` (phase timings), ``data.json``
 (full arg dump), ``parts.csv`` (per-worker rows) — reference
 ``process_query.py:230-239``, with its multi-worker CSV crash fixed (the
 reference's ``[[i] + row for i, row in stats]`` mis-unpacks, SURVEY.md §2.1).
+The TPU path adds ``answers.npz`` (every answer, scenario order) and
+``progress.jsonl`` (:data:`PROGRESS_FILE`, written as the campaign runs).
 """
 
 from __future__ import annotations
@@ -29,8 +34,10 @@ from __future__ import annotations
 import csv
 import dataclasses
 import io
+import json
 import os
 import sys
+import time
 
 import numpy as np
 
@@ -83,6 +90,22 @@ H_SEARCH = obs_metrics.histogram(
 H_BATCHES = obs_metrics.counter("head_batches_total")
 H_BATCH_FAIL = obs_metrics.counter(
     "head_batches_failed_total", "batches whose stats row came back FAIL")
+#: programs JAX lowered in this process: the TPU campaign counts the
+#: ones that fall inside its rounds (none, once warm)
+H_LOWERED = obs_metrics.counter(
+    "head_programs_lowered_total",
+    "programs JAX lowered in the campaign process (compiled, or loaded "
+    "from the persistent compilation cache)")
+_LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_lowering_counted = False
+
+#: with ``-o``, the TPU campaign's stages as they happen, one JSON object
+#: per line (:class:`_Progress`)
+PROGRESS_FILE = "progress.jsonl"
+#: the metrics each ``part`` record carries, cumulative
+PROGRESS_METRICS = ("cpd_route_seconds", "cpd_walk_seconds",
+                    "cpd_fetch_seconds", "cpd_pairs_total",
+                    "cpd_lanes_total", "head_programs_lowered_total")
 
 
 def runtime_config(args) -> RuntimeConfig:
@@ -113,6 +136,96 @@ def effective_partition(conf: ClusterConfig, args):
 
 
 # ------------------------------------------------------------------ TPU path
+
+def _count_lowerings() -> None:
+    """Book every program JAX lowers from now on in this process into
+    ``head_programs_lowered_total`` (idempotent)."""
+    global _lowering_counted
+    if _lowering_counted:
+        return
+    import jax
+
+    def on_event(event: str, _seconds: float, **_kw) -> None:
+        if event == _LOWERING_EVENT:
+            H_LOWERED.inc()
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    _lowering_counted = True
+
+
+class _Progress:
+    """``<-o>/progress.jsonl``: one JSON line per stage of the TPU
+    campaign, flushed as it happens, so that a watcher can follow the
+    rounds while they run (a no-op without ``-o`` and off the primary
+    process). Every line has ``event`` and ``t`` (``time.monotonic``,
+    the host's clock for every process). Events: ``start`` (before the
+    graph loads), ``loaded`` (the oracle resident; ``shard_devices``:
+    the device id holding each worker's rows), ``warm`` (``lanes``: the
+    routed widths compiled before the first part), and one ``part`` per
+    oracle call: ``round`` (the diff round, ``"fused"`` for the fused
+    path), ``part``, ``pairs``, ``t0``/``t1`` around the call, and
+    ``metrics``: :data:`PROGRESS_METRICS` so far, a histogram as
+    ``[sum, count]``."""
+
+    def __init__(self, outdir: str | None):
+        self._f = None
+        if outdir and is_primary():
+            os.makedirs(outdir, exist_ok=True)
+            # dos-lint: disable=atomic-writes -- an append-only log read
+            # while it grows; its readers skip a torn last line
+            self._f = open(os.path.join(outdir, PROGRESS_FILE), "w")
+
+    def write(self, event: str, **fields) -> None:
+        if self._f is None:
+            return
+        self._f.write(json.dumps({"event": event, "t": time.monotonic(),
+                                  **fields}) + "\n")
+        self._f.flush()
+
+    def part(self, rnd, k: int, pairs: int, t0: float, t1: float) -> None:
+        if self._f is None:
+            return
+        metrics = {}
+        for name in PROGRESS_METRICS:
+            if name.endswith("_seconds"):
+                h = obs_metrics.histogram(name)
+                metrics[name] = [h.sum, h.count]
+            else:
+                metrics[name] = obs_metrics.counter(name).value
+        self.write("part", round=rnd, part=k, pairs=pairs, t0=t0, t1=t1,
+                   metrics=metrics)
+
+    def close(self) -> None:
+        if self._f is not None:
+            self._f.close()
+            self._f = None
+
+
+def _parts(nq: int, size: int) -> list:
+    """Slices of the scenario, in order: parts of ``size`` pairs (the
+    last may be short), or one slice when ``size`` is 0."""
+    if size <= 0 or nq == 0:
+        return [slice(0, nq)]
+    return [slice(i, min(i + size, nq)) for i in range(0, nq, size)]
+
+
+def _in_parts(call, queries: np.ndarray, parts: list, progress: _Progress,
+              rnd):
+    """``call(queries[part])`` for each part in turn; its arrays (query
+    axis last) put together in scenario order."""
+    outs = None
+    for k, sl in enumerate(parts):
+        t0 = time.monotonic()
+        got = call(queries[sl])
+        t1 = time.monotonic()
+        progress.part(rnd, k, sl.stop - sl.start, t0, t1)
+        if outs is None:
+            outs = [np.zeros(np.shape(a)[:-1] + (len(queries),),
+                             np.asarray(a).dtype) for a in got]
+        for o, a in zip(outs, got):
+            o[..., sl] = a
+    return tuple(outs)
+
 
 class _StreamedServe:
     """Duck-typed stand-in for ``CPDOracle`` in :func:`run_tpu` when the
@@ -265,14 +378,30 @@ def run_tpu(conf: ClusterConfig, args, queries, dc, diffs):
     """All diff rounds in-process on the mesh; per-worker rows recovered
     from the routed results.
 
-    Per-worker timing semantics: one fused sharded XLA call answers the
-    whole round, so a per-worker wall clock does not exist. Each row's
+    ``-s P`` (``args.size_partitions``) answers each round in parts of P
+    pairs, in scenario order, one oracle call per part over the index
+    loaded once; before the first part every routed width the parts
+    produce is compiled. ``-s 0`` answers each round in one call. The
+    A* path always answers a round in one call.
+
+    Per-worker timing semantics: sharded XLA calls answer the whole
+    round, so a per-worker wall clock does not exist. Each row's
     ``t_astar``/``t_search`` (and ``t_receive``/``t_prepare``) carry the
     worker's SHARE of the round interval, apportioned by walked moves
     (by batch size when no moves) — rows of a round sum to the measured
     round time, so downstream tooling that aggregates per-worker columns
     gets campaign-true totals (tests pin this).
     """
+    progress = _Progress(args.output)
+    try:
+        return _run_tpu(conf, args, queries, dc, diffs, progress)
+    finally:
+        progress.close()
+
+
+def _run_tpu(conf: ClusterConfig, args, queries, dc, diffs,
+             progress: _Progress):
+    progress.write("start", pairs=int(len(queries)))
     import jax
 
     from ..data.graph import Graph
@@ -340,12 +469,25 @@ def run_tpu(conf: ClusterConfig, args, queries, dc, diffs):
                          conf.outdir)
                 oracle.build(chunk=args.chunk)
                 oracle.save(conf.outdir)
+            progress.write("loaded", shard_devices={
+                int(s.index[0].start or 0): s.device.id
+                for s in oracle.fm.addressable_shards})
 
     owner = dc.worker_of(queries[:, 1])
     time_ns = get_time_ns(args)
     stats = []
     answers = []
     paths = None
+    parts = _parts(len(queries), args.size_partitions)
+    # parts of a fixed size, over one loaded index: compile every routed
+    # width they produce before the first, as the inproc gateway warms
+    # every batch size before it listens
+    warm = (args.size_partitions > 0 and hasattr(oracle, "warm")
+            and len(queries) > 0)
+    lanes = (sorted({oracle.routed_lanes(queries[sl], args.worker)
+                     for sl in parts}) if warm else [])
+    _count_lowerings()
+    lowered0 = H_LOWERED.value
     # fused multi-diff: table-search trajectories are diff-independent
     # (moves follow the FREE-FLOW first-move table), so a multi-diff
     # campaign — the reference's one-round-per-diff loop — walks ONCE
@@ -360,10 +502,14 @@ def run_tpu(conf: ClusterConfig, args, queries, dc, diffs):
             w_list = [None if d == "-"
                       else graph.weights_with_diff(read_diff(d))
                       for d in diffs]
+        if warm:
+            _warm(oracle, lanes, progress, w_diffs=w_list)
         with Timer() as fsearch, obs_trace.span("head.search", fused=True,
                                                 rounds=len(diffs)):
-            f_cost, f_plen, f_fin = oracle.query_multi(
-                queries, w_list, active_worker=args.worker)
+            f_cost, f_plen, f_fin = _in_parts(
+                lambda q: oracle.query_multi(q, w_list,
+                                             active_worker=args.worker),
+                queries, parts, progress, "fused")
         # histogram stays per-round like the sequential path (and like
         # the stats rows): one equal share per fused round
         for _ in diffs:
@@ -373,6 +519,15 @@ def run_tpu(conf: ClusterConfig, args, queries, dc, diffs):
                  fsearch.interval / len(diffs))
         log.info("fused %d diff rounds in one walk (%.3fs)",
                  len(diffs), fsearch.interval)
+    elif warm:
+        # a diffed round's weights are uploaded per call, free flow's
+        # are resident: both kinds compile apart (any weights of the
+        # right length stand in for a diff's)
+        for free in sorted({d == "-" for d in diffs}):
+            _warm(oracle, lanes, progress,
+                  w_query=None if free else graph.w,
+                  k_moves=args.k_moves)
+    lowered_warm = H_LOWERED.value
     for di, diff in enumerate(diffs):
         counters = {}
         active = (np.ones(len(queries), bool) if args.worker == -1
@@ -409,9 +564,11 @@ def run_tpu(conf: ClusterConfig, args, queries, dc, diffs):
             else:
                 with Timer() as search, obs_trace.span(
                         "head.search", alg="table-search", diff=diff):
-                    cost, plen, fin = oracle.query(
-                        queries, w_query=w_query, k_moves=args.k_moves,
-                        active_worker=args.worker)
+                    cost, plen, fin = _in_parts(
+                        lambda q: oracle.query(
+                            q, w_query=w_query, k_moves=args.k_moves,
+                            active_worker=args.worker),
+                        queries, parts, progress, di)
             prep_iv, search_iv = prep.interval, search.interval
             H_SEARCH.observe(search_iv)
         answers.append((cost, plen, fin))
@@ -448,6 +605,12 @@ def run_tpu(conf: ClusterConfig, args, queries, dc, diffs):
             rows.append(row.as_list(t_prepare=prep_iv * share,
                                     t_partition=0.0, size=size))
         stats.append(rows)
+    in_rounds = H_LOWERED.value - lowered_warm
+    if in_rounds and args.size_partitions > 0 and not use_astar:
+        log.warning("%d program(s) compiled inside the rounds, after "
+                    "the warm-up", in_rounds)
+    log.info("%d program(s) compiled before the rounds, %d inside them",
+             lowered_warm - lowered0, in_rounds)
     if getattr(args, "extract", False) and args.k_moves > 0:
         if use_astar:
             # reference semantics: "K-moves are only available with
@@ -473,6 +636,17 @@ def run_tpu(conf: ClusterConfig, args, queries, dc, diffs):
         atomic_write_bytes(os.path.join(args.output, "answers.npz"),
                            buf.getvalue())
     return stats, paths
+
+
+def _warm(oracle, lanes: list, progress: _Progress, **weights) -> None:
+    """Compile the walk at every routed width of the parts, before the
+    first part (``CPDOracle.warm``)."""
+    t0 = time.monotonic()
+    lowered0 = H_LOWERED.value
+    with obs_trace.span("head.warm", lanes=len(lanes)):
+        oracle.warm(lanes, **weights)
+    progress.write("warm", lanes=lanes, seconds=time.monotonic() - t0,
+                   programs=H_LOWERED.value - lowered0)
 
 
 # ----------------------------------------------------------------- host path

@@ -25,6 +25,7 @@ wrong for TPU; sharding is the compression here (SURVEY.md §7 hard parts).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import glob
 import io
@@ -131,6 +132,27 @@ M_MESH_COLLECTIVE = obs_metrics.histogram(
     "mesh_collective_seconds",
     "on-mesh collective join per mat-family row (query_mat: walk + "
     "scatter + psum, replacing the head-side fan-out/join)")
+
+# the mesh oracle's query stages (CPDOracle.query / query_multi): one
+# observation per call, on spans of the same names (cpd.route,
+# cpd.walk, cpd.fetch) tagged pairs= and lanes=
+M_ROUTE = obs_metrics.histogram(
+    "cpd_route_seconds",
+    "mesh query: host routing of the pairs to their owners' lanes "
+    "(group by owner, order by length estimate, pad)")
+M_WALK = obs_metrics.histogram(
+    "cpd_walk_seconds",
+    "mesh query: routed pairs and weights to the devices, the walk's "
+    "launch, until every shard's answers are ready")
+M_FETCH = obs_metrics.histogram(
+    "cpd_fetch_seconds",
+    "mesh query: answers to the host and back in input order")
+M_PAIRS = obs_metrics.counter(
+    "cpd_pairs_total", "pairs the mesh oracle answered")
+M_LANES = obs_metrics.counter(
+    "cpd_lanes_total",
+    "lanes the mesh walks ran, padding included (each worker's group "
+    "is padded to a power of two)")
 
 #: compressed device->host fm fetch below this raw size is not worth the
 #: extra device round trip (the count pass) — plain fetch instead
@@ -374,6 +396,16 @@ def block_complete(outdir: str, fname: str,
         log.debug("unledgered block %s unreadable (%s); rebuilding",
                   fname, e)
         return False
+
+
+@contextlib.contextmanager
+def _stage(name: str, hist, pairs: int, lanes: int):
+    """A mesh query stage: span ``name`` tagged ``pairs=`` and
+    ``lanes=``, and its time observed into ``hist``."""
+    t0 = time.perf_counter()
+    with obs_trace.span(name, pairs=pairs, lanes=lanes):
+        yield
+    hist.observe(time.perf_counter() - t0)
 
 
 def length_estimate(graph: Graph, s: np.ndarray, t: np.ndarray):
@@ -2064,11 +2096,33 @@ class CPDOracle:
     def _length_estimate(self, queries: np.ndarray) -> np.ndarray:
         return length_estimate(self.graph, queries[:, 0], queries[:, 1])
 
-    def route(self, queries: np.ndarray, active_worker: int = -1):
+    def _plan(self, queries: np.ndarray, active_worker: int = -1):
+        """``(wids, active, qmax)`` of a routed call: each query's owner,
+        the queries the call answers, and the lanes each (data slot,
+        worker) walks: the largest group over the data axis, bucketed
+        to the next power of two so that calls of similar size share
+        one compiled program."""
+        wids = self.dc.worker_of(queries[:, 1])
+        active = np.ones(len(queries), bool) if active_worker == -1 \
+            else wids == active_worker
+        sizes = np.bincount(wids[active], minlength=self.dc.maxworker)
+        d = self.mesh.shape[DATA_AXIS]
+        qmax = max(int(np.ceil(sizes.max() / d)) if active.any() else 0, 1)
+        return wids, active, 1 << (qmax - 1).bit_length()
+
+    def routed_lanes(self, queries: np.ndarray,
+                     active_worker: int = -1) -> int:
+        """Lanes per (data slot, worker) that a call over ``queries``
+        walks (:meth:`route`'s padded ``Q``)."""
+        return self._plan(np.asarray(queries, np.int64), active_worker)[2]
+
+    def route(self, queries: np.ndarray, active_worker: int = -1,
+              plan=None):
         """Pack (s, t) queries into mesh-shaped [D, W, Q] arrays.
 
         Returns ``(t_rows, s, t, valid, scatter)`` where ``scatter`` maps
         each input query to its (d, w, q) slot for unpacking results.
+        ``plan``: :meth:`_plan` of the same call, when the caller has it.
 
         Within each worker group, queries are ordered by expected walk
         length (:meth:`_length_estimate`) so the kernel's bucketed
@@ -2079,11 +2133,9 @@ class CPDOracle:
         nq = len(queries)
         d = self.mesh.shape[DATA_AXIS]
         w = self.dc.maxworker
-        wids = self.dc.worker_of(queries[:, 1])
+        wids, active, qmax = (self._plan(queries, active_worker)
+                              if plan is None else plan)
         rows = self.dc.owned_index_of(queries[:, 1])
-
-        active = np.ones(nq, bool) if active_worker == -1 \
-            else wids == active_worker
         # round-robin each worker's queries over the data axis (vectorized):
         # the k-th query of worker w goes to data slot k % d, column k // d
         slot_d = np.zeros(nq, np.int64)
@@ -2099,10 +2151,6 @@ class CPDOracle:
         seq = np.arange(len(idxs)) - np.repeat(starts, group_sizes)
         slot_d[idxs] = seq % d
         slot_q[idxs] = seq // d
-        qmax = max(int(np.ceil(group_sizes.max() / d)) if len(idxs) else 0, 1)
-        # bucket the padded length to the next power of two: stable shapes
-        # across calls -> no recompilation when the batch mix shifts
-        qmax = 1 << (qmax - 1).bit_length()
 
         s_arr = np.zeros((d, w, qmax), np.int32)
         t_arr = np.zeros((d, w, qmax), np.int32)
@@ -2137,6 +2185,54 @@ class CPDOracle:
             outs.append(out)
         return outs
 
+    def _staged(self, queries: np.ndarray, active_worker: int, walk,
+                lead_flags):
+        """One routed call in its three stages, each a span tagged
+        ``pairs=`` and ``lanes=`` and a histogram observation:
+        ``cpd.route`` (host), ``cpd.walk`` (``walk(routed)``: upload,
+        launch, every shard's answers ready), ``cpd.fetch`` (answers
+        to the host in input order)."""
+        plan = self._plan(queries, active_worker)
+        pairs = int(plan[1].sum())
+        lanes = self.mesh.shape[DATA_AXIS] * self.dc.maxworker * plan[2]
+        with _stage("cpd.route", M_ROUTE, pairs, lanes):
+            r_arr, s_arr, t_arr, valid, scatter = self.route(
+                queries, active_worker, plan)
+        with _stage("cpd.walk", M_WALK, pairs, lanes):
+            outs = jax.block_until_ready(walk((r_arr, s_arr, t_arr,
+                                               valid)))
+        with _stage("cpd.fetch", M_FETCH, pairs, lanes):
+            res = tuple(self._unroute(scatter, len(queries),
+                                      _host_tree(outs), lead_flags))
+        M_PAIRS.inc(pairs)
+        M_LANES.inc(lanes)
+        return res
+
+    def _device_weights(self, w_query: np.ndarray | None):
+        """Padded query-time weights on the device: free flow is
+        resident already; a diffed round pays one upload."""
+        return self.dg.w_pad if w_query is None else jnp.asarray(
+            self.graph.padded_weights(w_query), jnp.int32)
+
+    def _walk(self, w_query, k_moves: int, max_steps: int):
+        """``routed -> (cost, plen, finished)`` on the devices."""
+        def walk(routed):
+            return query_sharded(
+                self.dg, self.fm, *routed, self._device_weights(w_query),
+                self.mesh, k_moves=k_moves, max_steps=max_steps,
+                kernel=self._walk_kernel(routed[0].shape))
+        return walk
+
+    def _walk_multi(self, w_diffs, max_steps: int):
+        """``routed -> (cost [D, ...], plen, finished)``, one fused walk
+        costed under every diff of ``w_diffs``."""
+        def walk(routed):
+            return query_multi_sharded(
+                self.dg, self.fm, *routed,
+                self.graph.padded_weights_multi(w_diffs), self.mesh,
+                max_steps=max_steps)
+        return walk
+
     def query(self, queries: np.ndarray, w_query: np.ndarray | None = None,
               k_moves: int = -1, active_worker: int = -1,
               max_steps: int = 0):
@@ -2150,18 +2246,27 @@ class CPDOracle:
         """
         if self.fm is None:
             raise RuntimeError("build() or load() before query()")
-        r_arr, s_arr, t_arr, valid, scatter = self.route(
-            queries, active_worker)
-        # free-flow weights are already device-resident; only diffed runs
-        # pay a fresh host->device upload
-        w_pad = self.dg.w_pad if w_query is None else jnp.asarray(
-            self.graph.padded_weights(w_query), jnp.int32)
-        outs = _host_tree(query_sharded(
-            self.dg, self.fm, r_arr, s_arr, t_arr, valid, w_pad, self.mesh,
-            k_moves=k_moves, max_steps=max_steps,
-            kernel=self._walk_kernel(r_arr.shape)))
-        return tuple(self._unroute(scatter, len(queries), outs,
-                                   (False, False, False)))
+        return self._staged(np.asarray(queries, np.int64), active_worker,
+                            self._walk(w_query, k_moves, max_steps),
+                            (False, False, False))
+
+    def warm(self, lanes, w_query: np.ndarray | None = None,
+             w_diffs: list | None = None, k_moves: int = -1,
+             max_steps: int = 0) -> None:
+        """Compile (or load from the compilation cache) the walk that
+        :meth:`query` (or, with ``w_diffs``, :meth:`query_multi`) runs
+        at each routed width in ``lanes`` (:meth:`routed_lanes`), on
+        batches of padding only, so that no later call of those widths
+        compiles."""
+        if self.fm is None:
+            raise RuntimeError("build() or load() before warm()")
+        walk = (self._walk(w_query, k_moves, max_steps) if w_diffs is None
+                else self._walk_multi(w_diffs, max_steps))
+        shape = (self.mesh.shape[DATA_AXIS], self.dc.maxworker)
+        for q in sorted(set(lanes)):
+            idx = np.zeros(shape + (q,), np.int32)
+            jax.block_until_ready(walk(
+                (idx, idx, idx, np.zeros(shape + (q,), bool))))
 
     def _walk_kernel(self, routed_shape) -> str:
         """Resolve ``DOS_WALK_KERNEL`` for one routed batch. The policy
@@ -2198,14 +2303,9 @@ class CPDOracle:
             raise RuntimeError("build() or load() before query_multi()")
         if not w_diffs:
             raise ValueError("w_diffs must name at least one round")
-        r_arr, s_arr, t_arr, valid, scatter = self.route(
-            queries, active_worker)
-        w_pads = self.graph.padded_weights_multi(w_diffs)
-        outs = _host_tree(query_multi_sharded(
-            self.dg, self.fm, r_arr, s_arr, t_arr, valid, w_pads,
-            self.mesh, max_steps=max_steps))
-        return tuple(self._unroute(scatter, len(queries), outs,
-                                   (True, False, False)))
+        return self._staged(np.asarray(queries, np.int64), active_worker,
+                            self._walk_multi(w_diffs, max_steps),
+                            (True, False, False))
 
     def query_mat(self, s: int, targets,
                   w_query: np.ndarray | None = None,

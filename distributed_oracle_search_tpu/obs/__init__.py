@@ -76,6 +76,19 @@ batches): ``head_batches_total`` / ``head_batches_failed_total`` and
 ``server_replies_sent_total`` on the worker (sent replies are the
 complement of the drop counters below).
 
+Mesh campaign (``partmethod: "tpu"``, ``models.cpd.CPDOracle.query`` /
+``query_multi``, one observation per oracle call — a whole round, or
+one part of ``-s`` pairs): ``cpd_route_seconds`` (host routing),
+``cpd_walk_seconds`` (upload, launch, every shard's answers ready),
+``cpd_fetch_seconds`` (answers to the host in input order), each on a
+span of the same stage name (``cpd.route``, ``cpd.walk``,
+``cpd.fetch``) tagged ``pairs=`` and ``lanes=``;
+``cpd_pairs_total`` and ``cpd_lanes_total`` (lanes walked, padding
+included: their ratio is the lanes' fill);
+``head_programs_lowered_total`` (programs JAX lowered in the campaign
+process, compiled or loaded from the persistent cache: with ``-s`` the
+warm-up takes them all, span ``head.warm``, and the rounds none).
+
 Server failure paths (no stats-field analog — the reference dropped
 these on the floor): ``server_frames_received_total``,
 ``server_frames_malformed_total``, ``server_frames_half_total``,

@@ -577,7 +577,9 @@ def _query_fn(mesh: Mesh, max_steps: int, k_moves: int = -1,
               kernel: str = "xla"):
     q3 = P(DATA_AXIS, WORKER_AXIS, None)
 
-    def _local(dg, fm_local, rows, s, t, valid, w_pad):
+    # the function's name is the compiled program's (``jit_mesh_walk``):
+    # device traces find the campaign walk by it
+    def mesh_walk(dg, fm_local, rows, s, t, valid, w_pad):
         # local blocks: fm [1, R, N]; queries [D/|data|, 1, Q].
         # k_moves is part of THIS function's cache key (a per-campaign
         # constant), so the kernel sees a Python int and its static
@@ -597,7 +599,7 @@ def _query_fn(mesh: Mesh, max_steps: int, k_moves: int = -1,
         return (cost.reshape(shape), plen.reshape(shape), fin.reshape(shape))
 
     sm = jax.shard_map(
-        _local, mesh=mesh,
+        mesh_walk, mesh=mesh,
         in_specs=(P(), P(WORKER_AXIS, None, None), q3, q3, q3, q3, P()),
         out_specs=(q3, q3, q3),
         # JAX 0.9's Pallas interpreter (pallas/hlo_interpreter.py) mixes
@@ -617,7 +619,8 @@ def _query_multi_fn(mesh: Mesh, max_steps: int, d: int):
 
     q3 = P(DATA_AXIS, WORKER_AXIS, None)
 
-    def _local(dg, fm_local, rows, s, t, valid, w_pads):
+    # compiled as ``jit_mesh_walk_multi`` (see _query_fn)
+    def mesh_walk_multi(dg, fm_local, rows, s, t, valid, w_pads):
         fm2 = fm_local[0]
         shape = s.shape
         cost, plen, fin = table_search_multi(
@@ -627,7 +630,7 @@ def _query_multi_fn(mesh: Mesh, max_steps: int, d: int):
                 fin.reshape(shape))
 
     sm = jax.shard_map(
-        _local, mesh=mesh,
+        mesh_walk_multi, mesh=mesh,
         in_specs=(P(), P(WORKER_AXIS, None, None), q3, q3, q3, q3, P()),
         out_specs=(P(None, DATA_AXIS, WORKER_AXIS, None), q3, q3),
     )

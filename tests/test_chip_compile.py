@@ -37,13 +37,13 @@ HBM_BYTES = 16 * 10**9
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 -- any failure means no chip
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -52,9 +52,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture(scope="module")
@@ -113,3 +118,36 @@ def test_pallas_walk_compiles(one_chip):
         dg, _spec(one_chip, (8, g.n), jnp.int8), *q, dg.w_pad,
         _spec(one_chip, (QPAD,), jnp.bool_), k_moves=-1, max_steps=0,
         unroll=8, n_buckets=1, interpret=False, packed4=False).compile()
+
+
+def test_mesh_walk_compiles_at_campaign_width(topo):
+    """The campaign's walk on the 4-chip host (``city384-tpu4``): the
+    384x384 city's table, 36,864 target rows per chip, at the routed
+    width of a part of 2^16 uniform pairs; compiled under its stable
+    program name, with no copy of the table."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_oracle_search_tpu.parallel.mesh import (
+        DATA_AXIS, WORKER_AXIS,
+    )
+    from distributed_oracle_search_tpu.parallel.sharded import _query_fn
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4),
+                (DATA_AXIS, WORKER_AXIS))
+    g = synth_city_graph(384, 384, seed=0)
+    rows = -(-g.n // 4)
+    rep = NamedSharding(mesh, P())
+    q3 = NamedSharding(mesh, P(DATA_AXIS, WORKER_AXIS, None))
+    dg = _device_graph(rep, g)
+    q = [_spec(q3, (1, 4, 32768), jnp.int32) for _ in range(3)]
+    lowered = _query_fn(mesh, 0, -1, "xla").lower(
+        dg, _spec(NamedSharding(mesh, P(WORKER_AXIS, None, None)),
+                  (4, rows, g.n), jnp.int8),
+        *q, _spec(q3, (1, 4, 32768), jnp.bool_), dg.w_pad)
+    assert "module @jit_mesh_walk" in lowered.as_text()
+    compiled = lowered.compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+    # the walk reads the resident table in place: its temp is the
+    # lanes' state, far below one table copy
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * g.n // 100
