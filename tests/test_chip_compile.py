@@ -95,6 +95,23 @@ def test_xla_walk_compiles_at_shard_width(one_chip, city):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def test_serve_walk_holds_no_table_copy(one_chip, city):
+    """The served walk at the gateway's ``max_batch`` (64) gathers the
+    resident [12800, 102400] int8 shard in place: no s8 copy of it in
+    the program, and a temp far below the table's 1.31 GB."""
+    import re
+
+    dg = _device_graph(one_chip, city)
+    q = [_spec(one_chip, (64,), jnp.int32) for _ in range(3)]
+    compiled = jax.jit(table_search_batch).lower(
+        dg, _spec(one_chip, (ROWS, city.n), jnp.int8), *q, dg.w_pad,
+        _spec(one_chip, (64,), jnp.bool_)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    s8_copies = re.findall(r"= s8\[[^\]]*\]\S* copy(?:-start)?\(",
+                           compiled.as_text())
+    assert not s8_copies
+
+
 def test_build_kernel_compiles_for_one_block(one_chip, city):
     """The build kernel ``pick_build_kernel`` picks for the city, at
     one kernel call of rows."""

@@ -1,6 +1,7 @@
 """TPU ops vs CPU oracle: golden equality on distances, first moves, walks."""
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -219,6 +220,54 @@ def test_multi_diff_fused_walk_matches_sequential(toy_graph, dg,
     np.testing.assert_array_equal(np.asarray(cm[0]), np.asarray(c3))
     np.testing.assert_array_equal(np.asarray(pm), np.asarray(p3))
     np.testing.assert_array_equal(np.asarray(fmm), np.asarray(f3))
+
+
+def _all_eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it (jit,
+    scan, while, cond)."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    yield from _all_eqns(sub)
+
+
+@pytest.mark.parametrize("kernel", ["batch", "multi"])
+def test_walk_gathers_table_in_place(toy_graph, dg, toy_queries, kernel):
+    """The walk reads the first-move table in its resident [R, N]
+    layout: no reshape of the table operand (on the TPU a flattening
+    reshape copies the whole table every call), and its gathers read the
+    table itself."""
+    from distributed_oracle_search_tpu.ops.table_search import (
+        table_search_multi,
+    )
+
+    g = toy_graph
+    fm = build_fm_columns(dg, jnp.arange(5, dtype=jnp.int32))  # [5, N]
+    s = jnp.asarray(toy_queries[:, 0], jnp.int32)
+    t = jnp.asarray(toy_queries[:, 1] % 5, jnp.int32)
+    if kernel == "batch":
+        jaxpr = jax.make_jaxpr(table_search_batch)(dg, fm, t, s, t,
+                                                   dg.w_pad)
+    else:
+        w_pads = jnp.stack([dg.w_pad, dg.w_pad])
+        jaxpr = jax.make_jaxpr(table_search_multi)(dg, fm, t, s, t,
+                                                   w_pads)
+
+    def reads_table(eqn):
+        a = eqn.invars[0].aval
+        return a.shape == (5, g.n) and a.dtype == jnp.int8
+
+    eqns = list(_all_eqns(jaxpr.jaxpr))
+    assert not [e for e in eqns
+                if e.primitive.name == "reshape" and reads_table(e)]
+    assert [e for e in eqns
+            if e.primitive.name == "gather" and reads_table(e)]
 
 
 def test_route_sorts_by_length_estimate(toy_graph):
